@@ -50,11 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jaxpr types moved to jax.extend.core in newer jax releases
-    from jax.extend import core as jax_core
-    jax_core.ClosedJaxpr
-except (ImportError, AttributeError):
-    from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from benchmarks.policy_report import policy_accounting
 from repro.core import layer_program as lp

@@ -57,6 +57,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.policies import (BACKENDS, BACKEND_LOCAL, DTYPE_POLICIES,
                                  F32_CARRIER, FUSED_WINDOW, FUSION_POLICIES,
                                  INT8_NATIVE)
@@ -71,6 +72,7 @@ from repro.serve import (EventRequest, EventServeEngine, ExecutionPolicy,
 
 
 def main():
+    use_compile_cache()   # before anything compiles
     ap = argparse.ArgumentParser()
     ap.add_argument("--source", choices=("synthetic", "file"),
                     default="synthetic")

@@ -27,7 +27,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import shard_map
 
 
 def sd_cap(d_in: int, frac: float) -> int:
@@ -125,7 +124,7 @@ def _sd_matvec_sharded(w, x, x_ref, y_ref, cap, mesh):
         xr_new = xr + jax.lax.psum(upd, "data")
         return y_l, xr_new
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data", model_in_w), P(None, None), P(None, None),
                   P(None, model_in_w)),

@@ -7,8 +7,8 @@ Two deliberate public surfaces, nothing else:
 axis — across a 1-D device mesh named :data:`SLOT_AXIS`: per-shard
 membrane slabs, replicated weights.  :func:`slot_mesh` builds the mesh,
 :func:`slot_spec` / :func:`slot_sharding` place the slot-sharded tensors,
-:func:`replicated` places the weights, and the version-compat
-:func:`shard_map` wraps the fused window step over it.
+:func:`replicated` places the weights; ``jax.shard_map`` wraps the
+fused window step over it.
 
 **Logical-axis rules (the LM stack).**  Every parameter and activation in
 the model stack is annotated with *logical* axis names ("embed", "mlp",
@@ -43,27 +43,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 Axis = Union[str, Tuple[str, ...], None]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat ``shard_map``.
-
-    Newer jax exposes ``jax.shard_map`` with a ``check_vma`` flag; the
-    pinned container jax (0.4.x) only has
-    ``jax.experimental.shard_map.shard_map`` whose equivalent flag is
-    ``check_rep`` (transitional releases promote the function before the
-    rename, so the flag name is probed, not assumed).
-    """
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    flag = ("check_vma" if "check_vma" in inspect.signature(sm).parameters
-            else "check_rep")
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{flag: check_vma})
 
 
 # ---------------------------------------------------------------------------

@@ -16,17 +16,20 @@ field column in 48 cycles. On TPU the equivalent structure is:
     "long intervals of sparse input activity are compressed into dense
     computational phases".
 
-VMEM budget (BlockSpec accounting): v-block ``Hp*Wp*CO_BLK*4`` bytes +
-weight block ``K*K*Ci*CO_BLK*4`` + events ``E*8``. For the paper's largest
-layer (34x34 halo-padded spatial, 64 channels, K=5, Ci=16) a CO_BLK=64
-block costs 34*34*64*4 = 296 kB + 5*5*16*64*4 = 102 kB — far below the
-16 MB VMEM of a TPU core, leaving room for double buffering.
+Fast-memory budget: events are packed one int32 word each
+(`window_common.pack_event_chunks`) and staged in SMEM in chunks of at
+most `window_common.EVENT_CHUNK` words, one chunk per grid step along an
+"arbitrary" axis, with the slab resident in VMEM across the chunks.  VMEM
+holds the v-block ``Hp*Wp*CO_BLK*4`` bytes (lanes padded to 128) and the
+weight block ``K*K*Ci*CO_BLK*4``, whatever the event bucket: the paper's
+32768-event input rung fits as well as an 8-event one.
 
-The per-event inner loop performs a dynamic-offset read-modify-write on the
-VMEM slab. This is sublane-addressed (not MXU) work — the honest mapping of
-an inherently scatter-shaped algorithm; the channel axis (lane dimension,
-CO_BLK multiple of 128 when possible) is fully vectorised, which is the TPU
-analogue of SNE updating a whole receptive-field column per event.
+The per-event inner loop reads one SMEM word, unpacks ``(x, y, c)`` on
+the scalar unit, and performs a dynamic-offset read-modify-write on the
+VMEM slab.  This is sublane-addressed (not MXU) work — the honest mapping
+of an inherently scatter-shaped algorithm; the channel axis (lane
+dimension) is fully vectorised, which is the TPU analogue of SNE updating
+a whole receptive-field column per event.
 """
 from __future__ import annotations
 
@@ -37,47 +40,48 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.lif import LifParams, supports_idle_skip
-from repro.kernels.window_common import (clip_fire_reset, cold_tile_decay,
-                                         leak_boundary, saturate_int8,
-                                         tile_grid, window_acc_dtype)
+from repro.core.lif import LifParams
+from repro.kernels.window_common import (event_fields, for_each_event,
+                                         pack_event_chunks, tile_grid,
+                                         window_acc_dtype, window_grid_step)
 
 
-def _event_conv_batched_kernel(ev_ref, gate_ref, w_ref, v_ref, o_ref, *,
-                               K: int, n_events: int):
-    """One grid step: one slot's event batch against one channel slab.
+def _conv_add(acc_ref, w_ref, K: int):
+    """The conv scatter rule, one event: add its flipped ``(K, K, CO_BLK)``
+    weight patch at its halo coordinate (int4 codes fit int8 and promote
+    exactly to the accumulator on the add)."""
+    def add(x, y, c):
+        patch = w_ref[:, :, c, :].astype(acc_ref.dtype)
+        cur = acc_ref[0, pl.dslice(x, K), pl.dslice(y, K), :]
+        acc_ref[0, pl.dslice(x, K), pl.dslice(y, K), :] = cur + patch
+
+    return add
+
+
+def _event_conv_batched_kernel(ev_ref, w_ref, v_ref, o_ref, *, K: int,
+                               fields):
+    """One grid step: one chunk of one slot's events against one slab.
 
     The slot axis only selects which event batch / membrane slab is
     resident, exactly like the C-XBAR steering one stream to one slice;
-    the single-stream path is the N=1 special case of this kernel.
+    the single-stream path is the N=1 special case of this kernel.  The
+    last grid axis walks the slot's event chunks in order, with the slab
+    resident in ``o_ref`` across them.
 
-    ev_ref:   (1, E, 3) int32 — this slot's events (x, y, c).
-    gate_ref: (1, E, 1) — 1/0 valid/padding, same dtype as the v slab.
-    w_ref:    (K, K, Ci, CO_BLK) — flipped weights, shared by slots
-              (float32 carrier, or int8 codes on the native path).
-    v_ref:    (1, Hp, Wp, CO_BLK) — this slot's membrane slab (float32
-              carrier, or int8 storage on the native path).
-    o_ref:    (1, Hp, Wp, CO_BLK) — output slab in the *accumulator* dtype
-              (== v dtype on the carrier path; int32 on the native path,
-              so per-timestep sums never saturate mid-batch).
+    ev_ref: (1, CHUNK) int32 SMEM — packed events (halo coords), -1 pads.
+    w_ref:  (K, K, Ci, CO_BLK) — flipped weights, shared by slots
+            (float32 carrier, or int8 codes on the native path).
+    v_ref:  (1, Hp, Wp, CO_BLK) — this slot's membrane slab (float32
+            carrier, or int8 storage on the native path).
+    o_ref:  (1, Hp, Wp, CO_BLK) — output slab in the *accumulator* dtype
+            (== v dtype on the carrier path; int32 on the native path,
+            so per-timestep sums never saturate mid-batch).
     """
-    # Bring the slab into registers/VMEM once; all events accumulate on it.
-    o_ref[...] = v_ref[...].astype(o_ref.dtype)
+    @pl.when(pl.program_id(2) == 0)
+    def _load():
+        o_ref[...] = v_ref[...].astype(o_ref.dtype)
 
-    def body(i, _):
-        x = ev_ref[0, i, 0]
-        y = ev_ref[0, i, 1]
-        c = ev_ref[0, i, 2]
-        g = gate_ref[0, i, 0]
-        # (K, K, CO_BLK) patch for this event's input channel, gated; the
-        # product stays exact in every dtype pairing (gate is 1/0, int4
-        # codes fit int8) and promotes to o_ref's accumulator on the add.
-        patch = (w_ref[:, :, c, :] * g).astype(o_ref.dtype)
-        cur = o_ref[0, pl.dslice(x, K), pl.dslice(y, K), :]
-        o_ref[0, pl.dslice(x, K), pl.dslice(y, K), :] = cur + patch
-        return ()
-
-    jax.lax.fori_loop(0, n_events, body, ())
+    for_each_event(ev_ref, fields, _conv_add(o_ref, w_ref, K))
 
 
 @functools.partial(jax.jit, static_argnames=("co_blk", "interpret",
@@ -97,7 +101,7 @@ def event_conv_pallas(v: jnp.ndarray, weights: jnp.ndarray,
       v:        (Hp, Wp, Co) halo-padded membrane state.
       weights:  (K, K, Ci, Co) conv weights (unflipped; flipped here once).
       ev_xyc:   (E, 3) int32 events; coordinates already in halo coords.
-      ev_gate:  (E,) validity gate (cast to the slab dtype).
+      ev_gate:  (E,) 1/0 validity gate.
       co_blk:   output-channel block size (lane dimension of the slab).
       out_dtype: accumulator/result dtype (default: ``v.dtype``).  The
                 int8-native policy passes int8 slabs with ``jnp.int32``
@@ -117,10 +121,11 @@ def event_conv_batched_pallas(v: jnp.ndarray, weights: jnp.ndarray,
                               out_dtype=None):
     """Scatter N slots' event batches into N membrane slabs in one launch.
 
-    The batch (slot) axis is a grid dimension: grid step ``(n, co)`` owns
-    slot *n*'s ``(Hp, Wp, CO_BLK)`` slab and consumes slot *n*'s event
-    batch against it. Weights are shared across slots (one model serving
-    many streams — the C-XBAR multicast of a weight set to all slices).
+    The grid is ``(slot, channel block, event chunk)``: grid step
+    ``(n, co, k)`` applies chunk *k* of slot *n*'s packed events to slot
+    *n*'s ``(Hp, Wp, CO_BLK)`` slab, which stays resident across the
+    chunks.  Weights are shared across slots (one model serving many
+    streams — the C-XBAR multicast of a weight set to all slices).
 
     Per-slab accumulation order matches the single-stream kernel exactly,
     so outputs are bit-for-bit equal to running ``event_conv_pallas`` per
@@ -130,18 +135,17 @@ def event_conv_batched_pallas(v: jnp.ndarray, weights: jnp.ndarray,
       v:        (N, Hp, Wp, Co) halo-padded membrane states, one per slot.
       weights:  (K, K, Ci, Co) conv weights, shared (unflipped).
       ev_xyc:   (N, E, 3) int32 events per slot; halo coordinates.
-      ev_gate:  (N, E) float validity gates (0.0 = padding slot).
+      ev_gate:  (N, E) 1/0 validity gates (0 = padding slot).
       co_blk:   output-channel block size.
     """
     N, Hp, Wp, Co = v.shape
-    K = weights.shape[0]
+    K, Ci = weights.shape[0], weights.shape[2]
     if ev_xyc.shape[0] != N or ev_gate.shape[0] != N:
         raise ValueError(
             f"slot-axis mismatch: v has {N} slots, events "
             f"{ev_xyc.shape[0]}, gates {ev_gate.shape[0]}")
     out_dtype = v.dtype if out_dtype is None else jnp.dtype(out_dtype)
-    E = ev_xyc.shape[1]
-    if N == 0 or E == 0:
+    if N == 0 or ev_xyc.shape[1] == 0:
         # degenerate batch (idle-skip compaction can hand us an empty slot
         # or event axis) — a scatter of nothing is the identity; skip the
         # launch instead of building a zero-sized grid
@@ -150,121 +154,63 @@ def event_conv_batched_pallas(v: jnp.ndarray, weights: jnp.ndarray,
     if Co % co_blk:
         raise ValueError(f"Co={Co} not divisible by co_blk={co_blk}")
     w_f = jnp.flip(jnp.flip(weights, 0), 1)
-    gate3 = ev_gate.astype(v.dtype).reshape(N, E, 1)
+    fields = event_fields(Hp, Wp, Ci)
+    words = pack_event_chunks(ev_xyc, ev_gate, fields)  # (N, nk, 1, CH)
+    n_chunks, chunk = words.shape[1], words.shape[3]
 
-    grid = (N, Co // co_blk)
     return pl.pallas_call(
-        functools.partial(_event_conv_batched_kernel, K=K, n_events=E),
-        grid=grid,
+        functools.partial(_event_conv_batched_kernel, K=K, fields=fields),
+        grid=(N, Co // co_blk, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, E, 3), lambda n, co: (n, 0, 0)),   # slot events
-            pl.BlockSpec((1, E, 1), lambda n, co: (n, 0, 0)),   # slot gates
-            pl.BlockSpec((K, K, weights.shape[2], co_blk),
-                         lambda n, co: (0, 0, 0, co)),          # shared weights
+            pl.BlockSpec((None, None, 1, chunk),
+                         lambda n, co, k: (n, k, 0, 0),
+                         memory_space=pltpu.SMEM),      # event chunk
+            pl.BlockSpec((K, K, Ci, co_blk),
+                         lambda n, co, k: (0, 0, 0, co)),  # shared weights
             pl.BlockSpec((1, Hp, Wp, co_blk),
-                         lambda n, co: (n, 0, 0, co)),          # slot v slab
+                         lambda n, co, k: (n, 0, 0, co)),  # slot v slab
         ],
         out_specs=pl.BlockSpec((1, Hp, Wp, co_blk),
-                               lambda n, co: (n, 0, 0, co)),
+                               lambda n, co, k: (n, 0, 0, co)),
         out_shape=jax.ShapeDtypeStruct(v.shape, out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(ev_xyc, gate3, w_f, v)
+    )(words, w_f, v)
 
 
-def _event_conv_window_kernel(ev_ref, gate_ref, alive_ref, tiles_ref, w_ref,
-                              v_ref, v_out_ref, s_out_ref, acc_ref, *,
-                              K: int, halo: int, n_events: int,
-                              lif: LifParams, native: bool):
-    """One grid step: one slot's WHOLE window against one channel slab.
+def _event_conv_window_kernel(alive_ref, tiles_ref, ev_ref, w_ref, v_ref,
+                              v_out_ref, s_out_ref, acc_ref, *, K: int,
+                              fields, **window):
+    """One grid step: one event chunk of one timestep of one slot's window.
 
-    The fused form of `_event_conv_batched_kernel`: the timestep loop runs
-    *inside* the kernel, with the membrane carried in the ``acc_ref`` VMEM
-    scratch between iterations (the cluster state memory staying resident
-    across the whole window, not just one dense phase), so a window costs
-    one launch instead of T.  Per timestep the full executor chain runs —
-    ``leak -> scatter(events of t) -> clip -> fire -> reset`` — with the
-    boundary arithmetic delegated to `kernels.window_common` (bitwise the
-    per-step executor's).
+    The fused form of `_event_conv_batched_kernel`.  The grid is
+    ``(slot, channel block, timestep, event chunk)`` and the membrane is
+    carried in the ``acc_ref`` VMEM scratch across the last two axes (the
+    cluster state memory staying resident across the whole window, not
+    just one dense phase), so a window costs one launch instead of T.
+    `window_common.window_grid_step` runs the per-timestep chain around
+    this kernel's scatter, with its tile-sparse sweeps.
 
-    The leak/clip/fire/reset sweeps are predicated per interior tile on
-    ``tiles_ref`` (`window_common.tile_grid` geometry): a cold tile —
-    one no event can reach this window — skips every per-timestep sweep
-    and is settled with one analytic `cold_tile_decay` after the loop
-    (hard-reset layers only; an all-ones bitmap reproduces the dense
-    schedule exactly).  The event scatter and the whole-slab native
-    saturation / freeze stay unconditional, so halo cells and the
-    superset contract are handled exactly as in the dense kernel.
-
-    ev_ref:    (1, T, E, 3) int32 — this slot's packed window schedule
-               (events binned by timestep, halo coords).
-    gate_ref:  (1, T, E, 1) — per-timestep validity gates, accumulator
-               dtype.
-    alive_ref: (1, T) float32 — 1.0 where the slot has a real timestep.
-    tiles_ref: (1, nTx, nTy) int32 — interior tile activity bitmap.
+    alive_ref: (N, T) int32 SMEM (scalar prefetch) — per-timestep liveness.
+    tiles_ref: (N * nTx * nTy,) int32 SMEM (scalar prefetch) — interior
+               tile activity bitmaps, slot-major.
+    ev_ref:    (1, CHUNK) int32 SMEM — this chunk's packed events (halo
+               coords), -1 pads.
     w_ref:     (K, K, Ci, CO_BLK) — flipped weights, shared by slots.
     v_ref:     (1, Hp, Wp, CO_BLK) — membrane slab in *storage* dtype
                (float32 carrier / int8 native).
     v_out_ref: (1, Hp, Wp, CO_BLK) — final membrane, storage dtype.
-    s_out_ref: (1, T, Ho, Wo, CO_BLK) — per-timestep spike frames in the
+    s_out_ref: (1, 1, Ho, Wo, CO_BLK) — this timestep's spike frame in the
                accumulator dtype (what `frame_to_events` routes onward).
     acc_ref:   (1, Hp, Wp, CO_BLK) VMEM scratch, accumulator dtype — the
                resident membrane.
     """
-    acc_ref[...] = v_ref[...].astype(acc_ref.dtype)
-    s_out_ref[...] = jnp.zeros_like(s_out_ref)   # cold tiles never fire
-    T = s_out_ref.shape[1]
-    Hp, Wp = acc_ref.shape[1], acc_ref.shape[2]
-    h = halo
-    Ho, Wo = Hp - 2 * h, Wp - 2 * h
-    nTx, nTy, th, tw = tile_grid(Ho, Wo)
-    spans = [(ti, tj, ti * th, min((ti + 1) * th, Ho),
-              tj * tw, min((tj + 1) * tw, Wo))
-             for ti in range(nTx) for tj in range(nTy)]
-    for t in range(T):          # static trip count: T is the window shape
-        prev = acc_ref[...]     # value snapshot — the frozen-slot fallback
-        for ti, tj, x0, x1, y0, y1 in spans:
-            @pl.when(tiles_ref[0, ti, tj] > 0)
-            def _leak(x0=x0, x1=x1, y0=y0, y1=y1):
-                acc_ref[0, h + x0:h + x1, h + y0:h + y1, :] = leak_boundary(
-                    acc_ref[0, h + x0:h + x1, h + y0:h + y1, :], lif)
+    def scatter():
+        for_each_event(ev_ref, fields, _conv_add(acc_ref, w_ref, K))
 
-        def body(i, _, t=t):
-            x = ev_ref[0, t, i, 0]
-            y = ev_ref[0, t, i, 1]
-            c = ev_ref[0, t, i, 2]
-            g = gate_ref[0, t, i, 0]
-            patch = (w_ref[:, :, c, :] * g).astype(acc_ref.dtype)
-            cur = acc_ref[0, pl.dslice(x, K), pl.dslice(y, K), :]
-            acc_ref[0, pl.dslice(x, K), pl.dslice(y, K), :] = cur + patch
-            return ()
-
-        jax.lax.fori_loop(0, n_events, body, ())
-        a = alive_ref[0, t] > 0
-        for ti, tj, x0, x1, y0, y1 in spans:
-            @pl.when(tiles_ref[0, ti, tj] > 0)
-            def _fire(t=t, x0=x0, x1=x1, y0=y0, y1=y1):
-                v_new, s = clip_fire_reset(
-                    acc_ref[0, h + x0:h + x1, h + y0:h + y1, :], lif)
-                acc_ref[0, h + x0:h + x1, h + y0:h + y1, :] = v_new
-                s_out_ref[0, t, x0:x1, y0:y1, :] = jnp.where(
-                    a, s, jnp.zeros_like(s))
-        if native:
-            # int8 storage saturation at every boundary, halo included —
-            # exactly the per-step executor's whole-slab downcast
-            acc_ref[...] = saturate_int8(acc_ref[...])
-        acc_ref[...] = jnp.where(a, acc_ref[...], prev)
-    if supports_idle_skip(lif):
-        # settle cold tiles: dt alive boundaries of pure leak in one step
-        # (soft-reset layers never reach here — the ops wrapper rejects
-        # real bitmaps for them, and the all-ones dense bitmap has no
-        # cold tiles)
-        dtv = jnp.sum((alive_ref[0, :] > 0).astype(jnp.int32))
-        for ti, tj, x0, x1, y0, y1 in spans:
-            @pl.when(tiles_ref[0, ti, tj] == 0)
-            def _cold(x0=x0, x1=x1, y0=y0, y1=y1):
-                acc_ref[0, h + x0:h + x1, h + y0:h + y1, :] = cold_tile_decay(
-                    acc_ref[0, h + x0:h + x1, h + y0:h + y1, :], lif, dtv)
-    v_out_ref[...] = acc_ref[...].astype(v_out_ref.dtype)
+    window_grid_step(alive_ref, tiles_ref, v_ref, v_out_ref, s_out_ref,
+                     acc_ref, scatter, **window)
 
 
 @functools.partial(jax.jit, static_argnames=("lif", "halo", "co_blk",
@@ -278,8 +224,8 @@ def event_conv_window_pallas(v: jnp.ndarray, weights: jnp.ndarray,
 
     The fused window form of :func:`event_conv_batched_pallas`: instead of
     one scatter launch per timestep (with leak/fire between launches in
-    XLA), the timestep loop moves inside the kernel and the membrane slab
-    stays resident in VMEM scratch for the full window.  Results —
+    XLA), the timestep loop moves into the launch's grid and the membrane
+    slab stays resident in VMEM scratch for the full window.  Results —
     membrane AND every timestep's spike frame — are bitwise identical to
     iterating the per-step executor (`tests/test_fused_window.py`).
 
@@ -288,7 +234,7 @@ def event_conv_window_pallas(v: jnp.ndarray, weights: jnp.ndarray,
                (float32 carrier, int8 native).
       weights: (K, K, Ci, Co) conv weights (unflipped; flipped here once).
       ev_xyc:  (N, T, E, 3) int32 packed schedule, halo coordinates.
-      ev_gate: (N, T, E) validity gates (cast to the accumulator dtype).
+      ev_gate: (N, T, E) 1/0 validity gates.
       alive:   (N, T) 1.0 where the slot has a real timestep (frozen
                timesteps hold state and emit no spikes).
       tiles:   (N, nTx, nTy) int32 interior tile activity bitmap
@@ -304,46 +250,53 @@ def event_conv_window_pallas(v: jnp.ndarray, weights: jnp.ndarray,
     spikes (N, T, Ho, Wo, Co) accumulator dtype)``.
     """
     N, Hp, Wp, Co = v.shape
-    K = weights.shape[0]
-    T, E = ev_xyc.shape[1], ev_xyc.shape[2]
+    K, Ci = weights.shape[0], weights.shape[2]
+    T = ev_xyc.shape[1]
     Ho, Wo = Hp - 2 * halo, Wp - 2 * halo
     acc_dt = window_acc_dtype(v.dtype, native)
     co_blk = min(co_blk, Co)
     if Co % co_blk:
         raise ValueError(f"Co={Co} not divisible by co_blk={co_blk}")
     w_f = jnp.flip(jnp.flip(weights, 0), 1)
-    gate4 = ev_gate.astype(acc_dt).reshape(N, T, E, 1)
-    alive2 = alive.astype(jnp.float32)
+    fields = event_fields(Hp, Wp, Ci)
+    words = pack_event_chunks(ev_xyc, ev_gate, fields)  # (N, T, nk, 1, CH)
+    n_chunks, chunk = words.shape[2], words.shape[4]
     nTx, nTy, _, _ = tile_grid(Ho, Wo)
     if tiles.shape != (N, nTx, nTy):
         raise ValueError(
             f"tiles shape {tiles.shape} != {(N, nTx, nTy)} for interior "
             f"({Ho}, {Wo})")
-    tiles = tiles.astype(jnp.int32)
 
-    grid = (N, Co // co_blk)
     return pl.pallas_call(
-        functools.partial(_event_conv_window_kernel, K=K, halo=halo,
-                          n_events=E, lif=lif, native=native),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, T, E, 3), lambda n, co: (n, 0, 0, 0)),
-            pl.BlockSpec((1, T, E, 1), lambda n, co: (n, 0, 0, 0)),
-            pl.BlockSpec((1, T), lambda n, co: (n, 0)),
-            pl.BlockSpec((1, nTx, nTy), lambda n, co: (n, 0, 0)),
-            pl.BlockSpec((K, K, weights.shape[2], co_blk),
-                         lambda n, co: (0, 0, 0, co)),
-            pl.BlockSpec((1, Hp, Wp, co_blk), lambda n, co: (n, 0, 0, co)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, Hp, Wp, co_blk), lambda n, co: (n, 0, 0, co)),
-            pl.BlockSpec((1, T, Ho, Wo, co_blk),
-                         lambda n, co: (n, 0, 0, 0, co)),
-        ],
+        functools.partial(_event_conv_window_kernel, K=K, fields=fields,
+                          halo=halo, n_steps=T, n_chunks=n_chunks, lif=lif,
+                          native=native),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N, Co // co_blk, T, n_chunks),
+            in_specs=[
+                pl.BlockSpec((None, None, None, 1, chunk),
+                             lambda n, co, t, k, *_: (n, t, k, 0, 0),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((K, K, Ci, co_blk),
+                             lambda n, co, t, k, *_: (0, 0, 0, co)),
+                pl.BlockSpec((1, Hp, Wp, co_blk),
+                             lambda n, co, t, k, *_: (n, 0, 0, co)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, Hp, Wp, co_blk),
+                             lambda n, co, t, k, *_: (n, 0, 0, co)),
+                pl.BlockSpec((1, 1, Ho, Wo, co_blk),
+                             lambda n, co, t, k, *_: (n, t, 0, 0, co)),
+            ],
+            scratch_shapes=[pltpu.VMEM((1, Hp, Wp, co_blk), acc_dt)]),
         out_shape=[
             jax.ShapeDtypeStruct(v.shape, v.dtype),
             jax.ShapeDtypeStruct((N, T, Ho, Wo, Co), acc_dt),
         ],
-        scratch_shapes=[pltpu.VMEM((1, Hp, Wp, co_blk), acc_dt)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary")),
         interpret=interpret,
-    )(ev_xyc, gate4, alive2, tiles, w_f, v)
+    )((alive > 0).astype(jnp.int32), tiles.astype(jnp.int32).reshape(-1),
+      words, w_f, v)

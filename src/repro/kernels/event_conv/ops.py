@@ -95,13 +95,14 @@ def event_conv_window(v: jnp.ndarray, weights: jnp.ndarray,
         raise ValueError(
             "tile sparsity requires a hard-reset layer (reset_mode='zero'):"
             " cold-tile decay has no closed form under soft reset")
-    if use_pallas is False:
+    nTx, nTy, _, _ = tile_grid(v.shape[1] - 2 * halo, v.shape[2] - 2 * halo)
+    if use_pallas is False or nTx * nTy == 0:
+        # an empty interior (kernel wider than the padded input) has no
+        # neuron to tile or fire: its halo-only scatter runs on the oracle
         return event_conv_window_ref(v, weights, ev_xyc, ev_gate, alive,
                                      lif=lif, halo=halo, native=native,
                                      tiles=tiles)
     if tiles is None:
-        nTx, nTy, _, _ = tile_grid(v.shape[1] - 2 * halo,
-                                   v.shape[2] - 2 * halo)
         tiles = jnp.ones((v.shape[0], nTx, nTy), jnp.int32)
     return event_conv_window_pallas(v, weights, ev_xyc, ev_gate, alive,
                                     tiles, lif=lif, halo=halo,

@@ -16,8 +16,12 @@ mapping mirrors `kernels/event_conv/kernel.py`:
     select, which keeps the store lane-aligned instead of issuing a
     single-element scatter — the TPU-honest form of "one neuron update".
 
-Accumulation order per slab is the event order, exactly the reference
-oracle's, so results are bit-for-bit equal to `ref.event_pool_ref`.
+Events arrive packed one int32 word each and are staged in SMEM one
+chunk per grid step (`window_common.pack_event_chunks`), with the slab
+resident across the chunks, so the kernel's VMEM footprint does not grow
+with the event bucket.  Accumulation order per slab is the event order,
+exactly the reference oracle's, so results are bit-for-bit equal to
+`ref.event_pool_ref`.
 """
 from __future__ import annotations
 
@@ -28,48 +32,62 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.lif import LifParams, supports_idle_skip
-from repro.kernels.window_common import (clip_fire_reset, cold_tile_decay,
-                                         leak_boundary, saturate_int8,
-                                         tile_grid, window_acc_dtype)
+from repro.core.lif import LifParams
+from repro.kernels.window_common import (event_fields, for_each_event,
+                                         pack_event_chunks, tile_grid,
+                                         window_acc_dtype, window_grid_step)
 
 
-def _event_pool_batched_kernel(ev_ref, gate_ref, w_ref, v_ref, o_ref, *,
-                               stride: int, n_events: int):
-    """One grid step: one slot's event batch against its pool slab.
+def _pool_add(acc_ref, w_ref, stride: int):
+    """The pool scatter rule, one event: ``v[x//s, y//s, c] += w[c]``.
 
-    ev_ref:   (1, E, 3) int32 — this slot's events (x, y, c), input coords.
-    gate_ref: (1, E, 1) — 1/0 valid/padding, same dtype as the v slab.
-    w_ref:    (1, 1, C) — per-channel weights, shared by slots (float32
-              carrier, or int8 codes on the native path).
-    v_ref:    (1, Ho, Wo, C) — this slot's membrane slab (float32 carrier,
-              or int8 storage on the native path).
-    o_ref:    (1, Ho, Wo, C) — output slab in the *accumulator* dtype
-              (== v dtype on the carrier path; int32 on the native path).
+    The channel axis (lane dimension) is updated as a full vector with a
+    one-hot channel select, which keeps the store lane-aligned instead of
+    issuing a single-element scatter.  Pooled coordinates past the grid
+    are dropped (the VALID-window rule: the contribution is zeroed and
+    the clamped read-modify-write is a no-op).
     """
-    o_ref[...] = v_ref[...].astype(o_ref.dtype)
-    Ho, Wo, C = o_ref.shape[1], o_ref.shape[2], o_ref.shape[3]
+    Ho, Wo, C = acc_ref.shape[1], acc_ref.shape[2], acc_ref.shape[3]
     lanes = jax.lax.broadcasted_iota(jnp.int32, (1, 1, C), 2)
 
-    def body(i, _):
-        x = ev_ref[0, i, 0]
-        y = ev_ref[0, i, 1]
-        c = ev_ref[0, i, 2]
-        g = gate_ref[0, i, 0]
+    def add(x, y, c):
         xo = x // stride
         yo = y // stride
-        # VALID-window rule: pooled coords past the grid are dropped (the
-        # gated contribution is zeroed; the clamped RMW is then a no-op)
-        ok = ((xo < Ho) & (yo < Wo)).astype(o_ref.dtype)
-        sel = (lanes == c).astype(o_ref.dtype)            # one-hot channel
-        contrib = sel * w_ref[...] * (g * ok)             # (1, 1, C)
+        ok = ((xo < Ho) & (yo < Wo)).astype(acc_ref.dtype)
+        sel = (lanes == c).astype(acc_ref.dtype)           # one-hot channel
+        contrib = (sel * w_ref[...] * ok).astype(acc_ref.dtype)
         xo = jnp.minimum(xo, Ho - 1)
         yo = jnp.minimum(yo, Wo - 1)
-        cur = o_ref[0, pl.dslice(xo, 1), pl.dslice(yo, 1), :]
-        o_ref[0, pl.dslice(xo, 1), pl.dslice(yo, 1), :] = cur + contrib
-        return ()
+        cur = acc_ref[0, pl.dslice(xo, 1), pl.dslice(yo, 1), :]
+        acc_ref[0, pl.dslice(xo, 1), pl.dslice(yo, 1), :] = cur + contrib
 
-    jax.lax.fori_loop(0, n_events, body, ())
+    return add
+
+
+def _event_pool_batched_kernel(ev_ref, w_ref, v_ref, o_ref, *, stride: int,
+                               fields):
+    """One grid step: one chunk of one slot's events against its slab.
+
+    ev_ref: (1, CHUNK) int32 SMEM — packed events, input coords; -1 pads.
+    w_ref:  (1, 1, C) — per-channel weights, shared by slots (float32
+            carrier, or int8 codes on the native path).
+    v_ref:  (1, Ho, Wo, C) — this slot's membrane slab (float32 carrier,
+            or int8 storage on the native path).
+    o_ref:  (1, Ho, Wo, C) — output slab in the *accumulator* dtype
+            (== v dtype on the carrier path; int32 on the native path),
+            resident across the slot's event chunks.
+    """
+    @pl.when(pl.program_id(1) == 0)
+    def _load():
+        o_ref[...] = v_ref[...].astype(o_ref.dtype)
+
+    for_each_event(ev_ref, fields, _pool_add(o_ref, w_ref, stride))
+
+
+def _pool_fields(Ho: int, Wo: int, C: int, stride: int):
+    """Packed-event field widths for a pool layer's input coordinates
+    (any input row/column lies below ``(Ho + 1) * stride``)."""
+    return event_fields((Ho + 1) * stride, (Wo + 1) * stride, C)
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "interpret",
@@ -87,7 +105,7 @@ def event_pool_pallas(v: jnp.ndarray, w: jnp.ndarray, ev_xyc: jnp.ndarray,
       v:       (Ho, Wo, C) membrane state (no halo for pool layers).
       w:       (C,) per-channel synapse weights.
       ev_xyc:  (E, 3) int32 events in input coordinates.
-      ev_gate: (E,) validity gate (cast to the slab dtype).
+      ev_gate: (E,) 1/0 validity gate.
       stride:  pooling stride.
       out_dtype: accumulator/result dtype (default ``v.dtype``; the
                int8-native policy passes ``jnp.int32``).
@@ -95,6 +113,18 @@ def event_pool_pallas(v: jnp.ndarray, w: jnp.ndarray, ev_xyc: jnp.ndarray,
     return event_pool_batched_pallas(v[None], w, ev_xyc[None], ev_gate[None],
                                      stride=stride, interpret=interpret,
                                      out_dtype=out_dtype)[0]
+
+
+def _pool_weights(w: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """(C,) weights as the kernels' (1, 1, C) block.
+
+    Integer weight codes ride at their own width (int8) even when the
+    slab is widened (int32 "subtract"-leak case) — the launch must move
+    exactly the bytes `layer_program.scatter_launch_bytes` accounts for;
+    float weights keep the historical cast to the slab dtype.
+    """
+    w3 = w if jnp.issubdtype(w.dtype, jnp.integer) else w.astype(v.dtype)
+    return w3.reshape(1, 1, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "interpret",
@@ -105,11 +135,14 @@ def event_pool_batched_pallas(v: jnp.ndarray, w: jnp.ndarray,
                               out_dtype=None):
     """Scatter N slots' pooled event batches into N slabs in one launch.
 
+    The grid is ``(slot, event chunk)``; each slot's slab stays resident
+    across its chunks.
+
     Args:
       v:       (N, Ho, Wo, C) membrane states, one per slot.
       w:       (C,) per-channel weights, shared across slots.
       ev_xyc:  (N, E, 3) int32 events per slot, input coordinates.
-      ev_gate: (N, E) validity gates.
+      ev_gate: (N, E) 1/0 validity gates.
       stride:  pooling stride.
       out_dtype: accumulator/result dtype (default ``v.dtype``).
     """
@@ -119,113 +152,60 @@ def event_pool_batched_pallas(v: jnp.ndarray, w: jnp.ndarray,
             f"slot-axis mismatch: v has {N} slots, events "
             f"{ev_xyc.shape[0]}, gates {ev_gate.shape[0]}")
     out_dtype = v.dtype if out_dtype is None else jnp.dtype(out_dtype)
-    E = ev_xyc.shape[1]
-    if N == 0 or E == 0:
+    if N == 0 or ev_xyc.shape[1] == 0:
         # degenerate batch (idle-skip compaction) — identity, skip the launch
         return v.astype(out_dtype)
-    gate3 = ev_gate.astype(v.dtype).reshape(N, E, 1)
-    # integer weight codes ride at their own width (int8) even when the
-    # slab is widened (int32 "subtract"-leak case) — the launch must move
-    # exactly the bytes `layer_program.scatter_launch_bytes` accounts for;
-    # float weights keep the historical cast to the slab dtype
-    w3 = (w if jnp.issubdtype(w.dtype, jnp.integer)
-          else w.astype(v.dtype)).reshape(1, 1, C)
+    fields = _pool_fields(Ho, Wo, C, stride)
+    words = pack_event_chunks(ev_xyc, ev_gate, fields)  # (N, nk, 1, CH)
+    n_chunks, chunk = words.shape[1], words.shape[3]
 
-    grid = (N,)
     return pl.pallas_call(
         functools.partial(_event_pool_batched_kernel, stride=stride,
-                          n_events=E),
-        grid=grid,
+                          fields=fields),
+        grid=(N, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, E, 3), lambda n: (n, 0, 0)),    # slot events
-            pl.BlockSpec((1, E, 1), lambda n: (n, 0, 0)),    # slot gates
-            pl.BlockSpec((1, 1, C), lambda n: (0, 0, 0)),    # shared weights
-            pl.BlockSpec((1, Ho, Wo, C), lambda n: (n, 0, 0, 0)),
+            pl.BlockSpec((None, None, 1, chunk), lambda n, k: (n, k, 0, 0),
+                         memory_space=pltpu.SMEM),           # event chunk
+            pl.BlockSpec((1, 1, C), lambda n, k: (0, 0, 0)),  # shared weights
+            pl.BlockSpec((1, Ho, Wo, C), lambda n, k: (n, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Ho, Wo, C), lambda n: (n, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, Ho, Wo, C), lambda n, k: (n, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(v.shape, out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(ev_xyc, gate3, w3, v)
+    )(words, _pool_weights(w, v), v)
 
 
-def _event_pool_window_kernel(ev_ref, gate_ref, alive_ref, tiles_ref, w_ref,
-                              v_ref, v_out_ref, s_out_ref, acc_ref, *,
-                              stride: int, n_events: int, lif: LifParams,
-                              native: bool):
-    """One grid step: one slot's WHOLE window against its pool slab.
+def _event_pool_window_kernel(alive_ref, tiles_ref, ev_ref, w_ref, v_ref,
+                              v_out_ref, s_out_ref, acc_ref, *, stride: int,
+                              fields, **window):
+    """One grid step: one event chunk of one timestep of one slot's window.
 
-    The fused form of `_event_pool_batched_kernel`: the timestep loop runs
-    inside the kernel with the membrane in ``acc_ref`` VMEM scratch, one
-    launch per window instead of T.  Pool layers have no halo, so the
-    whole slab is the interior the LIF boundary runs on; the boundary
-    arithmetic comes from `kernels.window_common` (bitwise the per-step
-    executor's).  As in the conv window kernel, the leak/clip/fire sweeps
-    are predicated per tile on ``tiles_ref`` and cold tiles settle with
-    one `cold_tile_decay` after the loop; the scatter stays unconditional.
+    The fused form of `_event_pool_batched_kernel`: the grid is ``(slot,
+    1, timestep, event chunk)`` (the unit axis keeps the window grid
+    layout of the conv and FC kernels) with the membrane in ``acc_ref``
+    VMEM scratch across the last two axes, one launch per window instead
+    of T.  Pool layers have no halo, so the whole slab is the interior;
+    `window_common.window_grid_step` runs the per-timestep chain around
+    this kernel's scatter.
 
-    ev_ref:    (1, T, E, 3) int32 — packed window schedule, input coords.
-    gate_ref:  (1, T, E, 1) — per-timestep gates, accumulator dtype.
-    alive_ref: (1, T) float32 — per-timestep liveness.
-    tiles_ref: (1, nTx, nTy) int32 — tile activity bitmap over (Ho, Wo).
+    alive_ref: (N, T) int32 SMEM (scalar prefetch) — per-timestep liveness.
+    tiles_ref: (N * nTx * nTy,) int32 SMEM (scalar prefetch) — tile
+               activity bitmaps over (Ho, Wo), slot-major.
+    ev_ref:    (1, CHUNK) int32 SMEM — packed events, input coords.
     w_ref:     (1, 1, C) — per-channel weights, shared by slots.
     v_ref:     (1, Ho, Wo, C) — membrane slab, storage dtype.
     v_out_ref: (1, Ho, Wo, C) — final membrane, storage dtype.
-    s_out_ref: (1, T, Ho, Wo, C) — spike frames, accumulator dtype.
+    s_out_ref: (1, 1, Ho, Wo, C) — this timestep's spike frame,
+               accumulator dtype.
     acc_ref:   (1, Ho, Wo, C) VMEM scratch, accumulator dtype.
     """
-    acc_ref[...] = v_ref[...].astype(acc_ref.dtype)
-    s_out_ref[...] = jnp.zeros_like(s_out_ref)   # cold tiles never fire
-    T = s_out_ref.shape[1]
-    Ho, Wo, C = acc_ref.shape[1], acc_ref.shape[2], acc_ref.shape[3]
-    nTx, nTy, th, tw = tile_grid(Ho, Wo)
-    spans = [(ti, tj, ti * th, min((ti + 1) * th, Ho),
-              tj * tw, min((tj + 1) * tw, Wo))
-             for ti in range(nTx) for tj in range(nTy)]
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, 1, C), 2)
-    for t in range(T):
-        prev = acc_ref[...]
-        for ti, tj, x0, x1, y0, y1 in spans:
-            @pl.when(tiles_ref[0, ti, tj] > 0)
-            def _leak(x0=x0, x1=x1, y0=y0, y1=y1):
-                acc_ref[0, x0:x1, y0:y1, :] = leak_boundary(
-                    acc_ref[0, x0:x1, y0:y1, :], lif)
+    def scatter():
+        for_each_event(ev_ref, fields, _pool_add(acc_ref, w_ref, stride))
 
-        def body(i, _, t=t):
-            x = ev_ref[0, t, i, 0]
-            y = ev_ref[0, t, i, 1]
-            c = ev_ref[0, t, i, 2]
-            g = gate_ref[0, t, i, 0]
-            xo = x // stride
-            yo = y // stride
-            ok = ((xo < Ho) & (yo < Wo)).astype(acc_ref.dtype)
-            sel = (lanes == c).astype(acc_ref.dtype)
-            contrib = (sel * w_ref[...] * (g * ok)).astype(acc_ref.dtype)
-            xo = jnp.minimum(xo, Ho - 1)
-            yo = jnp.minimum(yo, Wo - 1)
-            cur = acc_ref[0, pl.dslice(xo, 1), pl.dslice(yo, 1), :]
-            acc_ref[0, pl.dslice(xo, 1), pl.dslice(yo, 1), :] = cur + contrib
-            return ()
-
-        jax.lax.fori_loop(0, n_events, body, ())
-        a = alive_ref[0, t] > 0
-        for ti, tj, x0, x1, y0, y1 in spans:
-            @pl.when(tiles_ref[0, ti, tj] > 0)
-            def _fire(t=t, x0=x0, x1=x1, y0=y0, y1=y1):
-                v_new, s = clip_fire_reset(acc_ref[0, x0:x1, y0:y1, :], lif)
-                acc_ref[0, x0:x1, y0:y1, :] = v_new
-                s_out_ref[0, t, x0:x1, y0:y1, :] = jnp.where(
-                    a, s, jnp.zeros_like(s))
-        if native:
-            acc_ref[...] = saturate_int8(acc_ref[...])
-        acc_ref[...] = jnp.where(a, acc_ref[...], prev)
-    if supports_idle_skip(lif):
-        dtv = jnp.sum((alive_ref[0, :] > 0).astype(jnp.int32))
-        for ti, tj, x0, x1, y0, y1 in spans:
-            @pl.when(tiles_ref[0, ti, tj] == 0)
-            def _cold(x0=x0, x1=x1, y0=y0, y1=y1):
-                acc_ref[0, x0:x1, y0:y1, :] = cold_tile_decay(
-                    acc_ref[0, x0:x1, y0:y1, :], lif, dtv)
-    v_out_ref[...] = acc_ref[...].astype(v_out_ref.dtype)
+    window_grid_step(alive_ref, tiles_ref, v_ref, v_out_ref, s_out_ref,
+                     acc_ref, scatter, halo=0, **window)
 
 
 @functools.partial(jax.jit, static_argnames=("lif", "stride", "native",
@@ -244,7 +224,7 @@ def event_pool_window_pallas(v: jnp.ndarray, w: jnp.ndarray,
       v:       (N, Ho, Wo, C) membranes, storage dtype.
       w:       (C,) per-channel weights, shared across slots.
       ev_xyc:  (N, T, E, 3) int32 packed schedule, input coordinates.
-      ev_gate: (N, T, E) validity gates.
+      ev_gate: (N, T, E) 1/0 validity gates.
       alive:   (N, T) per-timestep liveness.
       tiles:   (N, nTx, nTy) int32 tile activity bitmap over (Ho, Wo);
                all-ones runs the dense schedule bit-for-bit.
@@ -256,40 +236,46 @@ def event_pool_window_pallas(v: jnp.ndarray, w: jnp.ndarray,
     spikes (N, T, Ho, Wo, C) accumulator dtype)``.
     """
     N, Ho, Wo, C = v.shape
-    T, E = ev_xyc.shape[1], ev_xyc.shape[2]
+    T = ev_xyc.shape[1]
     acc_dt = window_acc_dtype(v.dtype, native)
-    gate4 = ev_gate.astype(acc_dt).reshape(N, T, E, 1)
-    alive2 = alive.astype(jnp.float32)
-    w3 = (w if jnp.issubdtype(w.dtype, jnp.integer)
-          else w.astype(v.dtype)).reshape(1, 1, C)
+    fields = _pool_fields(Ho, Wo, C, stride)
+    words = pack_event_chunks(ev_xyc, ev_gate, fields)  # (N, T, nk, 1, CH)
+    n_chunks, chunk = words.shape[2], words.shape[4]
     nTx, nTy, _, _ = tile_grid(Ho, Wo)
     if tiles.shape != (N, nTx, nTy):
         raise ValueError(
             f"tiles shape {tiles.shape} != {(N, nTx, nTy)} for interior "
             f"({Ho}, {Wo})")
-    tiles = tiles.astype(jnp.int32)
 
-    grid = (N,)
     return pl.pallas_call(
         functools.partial(_event_pool_window_kernel, stride=stride,
-                          n_events=E, lif=lif, native=native),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, T, E, 3), lambda n: (n, 0, 0, 0)),
-            pl.BlockSpec((1, T, E, 1), lambda n: (n, 0, 0, 0)),
-            pl.BlockSpec((1, T), lambda n: (n, 0)),
-            pl.BlockSpec((1, nTx, nTy), lambda n: (n, 0, 0)),
-            pl.BlockSpec((1, 1, C), lambda n: (0, 0, 0)),
-            pl.BlockSpec((1, Ho, Wo, C), lambda n: (n, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, Ho, Wo, C), lambda n: (n, 0, 0, 0)),
-            pl.BlockSpec((1, T, Ho, Wo, C), lambda n: (n, 0, 0, 0, 0)),
-        ],
+                          fields=fields, n_steps=T, n_chunks=n_chunks,
+                          lif=lif, native=native),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N, 1, T, n_chunks),
+            in_specs=[
+                pl.BlockSpec((None, None, None, 1, chunk),
+                             lambda n, b, t, k, *_: (n, t, k, 0, 0),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, 1, C), lambda n, b, t, k, *_: (0, 0, 0)),
+                pl.BlockSpec((1, Ho, Wo, C),
+                             lambda n, b, t, k, *_: (n, 0, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, Ho, Wo, C),
+                             lambda n, b, t, k, *_: (n, 0, 0, 0)),
+                pl.BlockSpec((1, 1, Ho, Wo, C),
+                             lambda n, b, t, k, *_: (n, t, 0, 0, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((1, Ho, Wo, C), acc_dt)]),
         out_shape=[
             jax.ShapeDtypeStruct(v.shape, v.dtype),
             jax.ShapeDtypeStruct((N, T, Ho, Wo, C), acc_dt),
         ],
-        scratch_shapes=[pltpu.VMEM((1, Ho, Wo, C), acc_dt)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary")),
         interpret=interpret,
-    )(ev_xyc, gate4, alive2, tiles, w3, v)
+    )((alive > 0).astype(jnp.int32), tiles.astype(jnp.int32).reshape(-1),
+      words, _pool_weights(w, v), v)

@@ -92,12 +92,14 @@ def event_pool_window(v: jnp.ndarray, w: jnp.ndarray, ev_xyc: jnp.ndarray,
         raise ValueError(
             "tile sparsity requires a hard-reset layer (reset_mode='zero'):"
             " cold-tile decay has no closed form under soft reset")
-    if use_pallas is False:
+    nTx, nTy, _, _ = tile_grid(v.shape[1], v.shape[2])
+    if use_pallas is False or nTx * nTy == 0:
+        # an empty pooled grid has no neuron to tile or fire: the oracle
+        # returns its (empty) window without a zero-sized launch
         return event_pool_window_ref(v, w, ev_xyc, ev_gate, alive, lif=lif,
                                      stride=stride, native=native,
                                      tiles=tiles)
     if tiles is None:
-        nTx, nTy, _, _ = tile_grid(v.shape[1], v.shape[2])
         tiles = jnp.ones((v.shape[0], nTx, nTy), jnp.int32)
     return event_pool_window_pallas(v, w, ev_xyc, ev_gate, alive, tiles,
                                     lif=lif, stride=stride, native=native,

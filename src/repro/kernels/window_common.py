@@ -9,7 +9,13 @@ per-step executor (`core.layer_program.layer_timestep`), which is the
 fused path's exactness oracle — so the boundary ops are not re-derived
 here: :func:`leak_boundary` and :func:`clip_fire_reset` call straight into
 `core.lif` (`apply_leak`, `fire_and_reset`), the single source both
-executors share.
+executors share.  :func:`window_grid_step` is the one per-grid-step body
+all three window kernels run around their own scatter.
+
+Every scatter kernel, per-step and window, also takes its events through
+here: :func:`pack_event_chunks` packs them one int32 word each into
+SMEM-sized chunks and :func:`for_each_event` is the in-kernel loop that
+unpacks them.
 
 This module is a *leaf* on the kernel side of the layering: it may import
 `core.lif` / `core.quant` (which import no kernels), and every kernel
@@ -20,20 +26,23 @@ restated here rather than imported from the executor.
 """
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from repro.core.lif import (LifParams, apply_leak, fire_and_reset,
                             idle_decay, supports_idle_skip)
 from repro.core.quant import INT8_MAX, INT8_MIN
 
-__all__ = ["INT8_MAX", "INT8_MIN", "clip_fire_reset", "cold_tile_decay",
-           "crop_interior", "dilate_conv", "dilate_pool", "fused_window_ref",
-           "leak_boundary", "pad_empty_schedule", "route_frame",
-           "saturate_int8", "seed_site_map", "sites_to_tiles", "tile_grid",
-           "tiles_to_sites", "window_acc_dtype", "write_cropped"]
+__all__ = ["EVENT_CHUNK", "INT8_MAX", "INT8_MIN", "clip_fire_reset",
+           "cold_tile_decay", "crop_interior", "dilate_conv", "dilate_pool",
+           "event_fields", "for_each_event", "fused_window_ref",
+           "leak_boundary", "pack_event_chunks", "pad_empty_schedule",
+           "route_frame", "saturate_int8", "seed_site_map", "sites_to_tiles",
+           "tile_grid", "tile_spans", "tiles_to_sites", "window_acc_dtype",
+           "window_grid_step", "write_cropped"]
 
 # Tiles per spatial axis of one membrane interior.  4x4 matches the
 # window kernels' launch geometry (whole-interior blocks): a tile is the
@@ -41,6 +50,83 @@ __all__ = ["INT8_MAX", "INT8_MIN", "clip_fire_reset", "cold_tile_decay",
 # breaking the lane (channel) axis, and 16 tiles keeps the per-timestep
 # predicate overhead negligible against the elementwise sweep it skips.
 TILE_GRID_MAX = 4
+
+# Most packed events one grid step stages in SMEM (4 KiB of int32 words).
+# The scatter kernels chunk the event axis over an "arbitrary" grid axis
+# with the membrane resident across chunks, so no VMEM block grows with
+# the event bucket: the paper network's 32768-event input rung costs the
+# same fast memory as an 8-event one.
+EVENT_CHUNK = 1024
+
+
+def _field_bits(n: int) -> int:
+    """Bits that hold every value in ``[0, n)`` (at least one)."""
+    return max(1, (int(n) - 1).bit_length())
+
+
+def event_fields(x_end: int, y_end: int, c_end: int) -> Tuple[int, int]:
+    """Field widths ``(y_bits, c_bits)`` of one kernel's packed events.
+
+    An event ``(x, y, c)`` with ``x < x_end``, ``y < y_end``, ``c <
+    c_end`` packs into one non-negative int32 word
+    ``(x << (y_bits + c_bits)) | (y << c_bits) | c`` — the paper's
+    one-word event format (`core.events.pack_events`) cut down to the
+    three fields a scatter reads.  The widths are static per kernel
+    geometry; a geometry whose fields need more than 31 bits is refused.
+    """
+    xb, yb, cb = _field_bits(x_end), _field_bits(y_end), _field_bits(c_end)
+    if xb + yb + cb > 31:
+        raise ValueError(
+            f"event fields ({x_end}, {y_end}, {c_end}) need "
+            f"{xb + yb + cb} bits; a packed event holds 31")
+    return yb, cb
+
+
+def pack_event_chunks(ev_xyc: jnp.ndarray, ev_gate: jnp.ndarray,
+                      fields: Tuple[int, int]) -> jnp.ndarray:
+    """Pack an event schedule into SMEM-sized chunks of int32 words.
+
+    ``(..., E, 3)`` events with ``(..., E)`` gates become
+    ``(..., n_chunks, 1, chunk)`` words: gated-on events in their order,
+    gated-off (padding) events as ``-1``, which the kernels skip.  The
+    chunk is at most :data:`EVENT_CHUNK` words and the event axis is
+    padded with ``-1`` to ``n_chunks * chunk`` (at most one word per
+    chunk of padding; an empty axis becomes one padding word).
+    """
+    yb, cb = fields
+    x, y, c = ev_xyc[..., 0], ev_xyc[..., 1], ev_xyc[..., 2]
+    word = (x << (yb + cb)) | (y << cb) | c
+    word = jnp.where(ev_gate > 0, word, -1).astype(jnp.int32)
+    E = word.shape[-1]
+    n_chunks = max(1, -(-E // EVENT_CHUNK))
+    chunk = max(1, -(-E // n_chunks))
+    pad = n_chunks * chunk - E
+    if pad:
+        word = jnp.pad(word, [(0, 0)] * (word.ndim - 1) + [(0, pad)],
+                       constant_values=-1)
+    return word.reshape(word.shape[:-1] + (n_chunks, 1, chunk))
+
+
+def for_each_event(ev_ref, fields: Tuple[int, int], update) -> None:
+    """Run ``update(x, y, c)`` for each real event of the staged chunk.
+
+    ``ev_ref`` is the ``(1, chunk)`` SMEM block of packed words
+    (:func:`pack_event_chunks`); events are applied in order, padding
+    words (``-1``) are skipped.  Shared by every scatter kernel so the
+    unpacking rule has one home.
+    """
+    yb, cb = fields
+
+    def body(i, carry):
+        word = ev_ref[0, i]
+
+        @pl.when(word >= 0)
+        def _apply():
+            update(word >> (yb + cb), (word >> cb) & ((1 << yb) - 1),
+                   word & ((1 << cb) - 1))
+        return carry
+
+    jax.lax.fori_loop(0, ev_ref.shape[-1], body, 0)
 
 
 def pad_empty_schedule(ev_xyc: jnp.ndarray, ev_gate: jnp.ndarray):
@@ -185,11 +271,25 @@ def tile_grid(H: int, W: int, max_tiles: int = TILE_GRID_MAX):
     At most ``max_tiles`` tiles per axis; edge tiles may be smaller (prime
     geometries stay exact — the kernels slice tiles with static bounds
     clamped to the interior).  Every tile is non-empty by construction:
-    ``nT = ceil(dim / ceil(dim / min(dim, max_tiles)))``.
+    ``nT = ceil(dim / ceil(dim / min(dim, max_tiles)))``.  An empty axis
+    (a conv whose kernel overhangs its padded input) has no tiles.
     """
-    th = -(-H // min(H, max_tiles))
-    tw = -(-W // min(W, max_tiles))
-    return (-(-H // th), -(-W // tw), th, tw)
+    def axis(d):
+        if d == 0:
+            return 0, 1
+        t = -(-d // min(d, max_tiles))
+        return -(-d // t), t
+
+    (nTx, th), (nTy, tw) = axis(H), axis(W)
+    return (nTx, nTy, th, tw)
+
+
+def tile_spans(H: int, W: int):
+    """Static ``(ti, tj, x0, x1, y0, y1)`` bounds of every interior tile."""
+    nTx, nTy, th, tw = tile_grid(H, W)
+    return [(ti, tj, ti * th, min((ti + 1) * th, H),
+             tj * tw, min((tj + 1) * tw, W))
+            for ti in range(nTx) for tj in range(nTy)]
 
 
 def seed_site_map(ev_xyc: jnp.ndarray, ev_gate: jnp.ndarray,
@@ -280,6 +380,99 @@ def cold_tile_decay(v: jnp.ndarray, lif: LifParams, dt) -> jnp.ndarray:
     the dense path too); ``dt == 0`` is a bitwise no-op.
     """
     return idle_decay(v, lif, dt)
+
+
+def window_grid_step(alive_ref, tiles_ref, v_ref, v_out_ref, s_out_ref,
+                     acc_ref, scatter: Callable[[], None], *, halo: int,
+                     n_steps: int, n_chunks: int, lif: LifParams,
+                     native: bool) -> None:
+    """One grid step ``(slot, block, timestep, event chunk)`` of a fused
+    window kernel — the shared body of every ``*_window`` kernel.
+
+    The membrane lives in ``acc_ref`` (VMEM scratch) across the timestep
+    and chunk axes.  Per timestep the executor chain runs — ``leak`` on
+    the first chunk, ``scatter()`` (the layer kind's event loop over the
+    staged chunk) on every chunk, ``clip -> fire -> reset`` on the last —
+    with the arithmetic of :func:`leak_boundary` / :func:`clip_fire_reset`
+    (bitwise the per-step executor's).  A frozen timestep (``alive == 0``)
+    runs nothing: it holds state and its spike frame stays zero.
+
+    The leak/clip/fire sweeps are predicated per interior tile
+    (:func:`tile_grid`) on the bitmap in ``tiles_ref``; a cold tile skips
+    them and settles with one :func:`cold_tile_decay` after the last
+    timestep (hard-reset layers only; an all-ones bitmap is the dense
+    schedule).  FC layers pass ``tiles_ref=None``: their one site is
+    always hot.  The scatter and the whole-slab native saturation stay
+    unconditional, so halo cells behave exactly as in the dense path.
+
+    alive_ref: (N, T) int32 SMEM (scalar prefetch) — per-timestep liveness.
+    tiles_ref: (N * nTx * nTy,) int32 SMEM (scalar prefetch) slot-major
+               tile bitmaps, or None.
+    v_ref:     (1, Hp, Wp, BLK) — membrane block in storage dtype.
+    v_out_ref: (1, Hp, Wp, BLK) — final membrane, storage dtype.
+    s_out_ref: (1, 1, Ho, Wo, BLK) — this timestep's spike frame,
+               accumulator dtype.
+    acc_ref:   (1, Hp, Wp, BLK) VMEM scratch, accumulator dtype.
+    """
+    n, t, k = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    h = halo
+    Ho, Wo = acc_ref.shape[1] - 2 * h, acc_ref.shape[2] - 2 * h
+    nTx, nTy, _, _ = tile_grid(Ho, Wo)
+    spans = tile_spans(Ho, Wo)
+    alive = alive_ref[n, t] > 0
+    first = k == 0
+    last = k == n_chunks - 1
+
+    def tile_on(ti, tj):
+        if tiles_ref is None:
+            return True
+        return tiles_ref[(n * nTx + ti) * nTy + tj] > 0
+
+    def region(x0, x1, y0, y1):
+        return (0, slice(h + x0, h + x1), slice(h + y0, h + y1), slice(None))
+
+    @pl.when((t == 0) & first)
+    def _load():
+        acc_ref[...] = v_ref[...].astype(acc_ref.dtype)
+
+    @pl.when(first)
+    def _clear():
+        s_out_ref[...] = jnp.zeros_like(s_out_ref)   # cold tiles never fire
+
+    for ti, tj, x0, x1, y0, y1 in spans:
+        @pl.when(first & alive & tile_on(ti, tj))
+        def _leak(r=region(x0, x1, y0, y1)):
+            acc_ref[r] = leak_boundary(acc_ref[r], lif)
+
+    pl.when(alive)(scatter)
+
+    for ti, tj, x0, x1, y0, y1 in spans:
+        @pl.when(last & alive & tile_on(ti, tj))
+        def _fire(r=region(x0, x1, y0, y1), x0=x0, x1=x1, y0=y0, y1=y1):
+            v_new, s = clip_fire_reset(acc_ref[r], lif)
+            acc_ref[r] = v_new
+            s_out_ref[0, 0, x0:x1, y0:y1, :] = s
+
+    if native:
+        @pl.when(last & alive)
+        def _saturate():
+            # int8 storage saturation at every boundary, halo included —
+            # exactly the per-step executor's whole-slab downcast
+            acc_ref[...] = saturate_int8(acc_ref[...])
+
+    @pl.when((t == n_steps - 1) & last)
+    def _store():
+        if tiles_ref is not None and supports_idle_skip(lif):
+            # settle cold tiles: dt alive boundaries of pure leak in one
+            # step (soft-reset layers never get a real bitmap — the ops
+            # wrappers refuse one — and all-ones has no cold tile)
+            dtv = sum((alive_ref[n, u] > 0).astype(jnp.int32)
+                      for u in range(n_steps))
+            for ti, tj, x0, x1, y0, y1 in spans:
+                @pl.when(jnp.logical_not(tile_on(ti, tj)))
+                def _cold(r=region(x0, x1, y0, y1)):
+                    acc_ref[r] = cold_tile_decay(acc_ref[r], lif, dtv)
+        v_out_ref[...] = acc_ref[...].astype(v_out_ref.dtype)
 
 
 def fused_window_ref(v: jnp.ndarray, ev_xyc: jnp.ndarray,

@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
 from repro.models.scan_util import xscan
 
 NEG_INF = -1e30
@@ -273,7 +272,7 @@ def flash_decode_shardmap(q: jnp.ndarray, k_cache: jnp.ndarray,
         o_g = o_g / jnp.maximum(l_g, 1e-30)[..., None]
         return o_g.reshape(qb.shape[0], 1, H, hd).astype(qb.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, P()),
         out_specs=q_spec,

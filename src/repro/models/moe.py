@@ -24,7 +24,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import logical, shard_map
+from repro.distributed.sharding import logical
 from repro.models.layers import DeclTree, ParamDecl, ParamTree, activation
 
 
@@ -220,7 +220,7 @@ def moe_apply_shardmap(p: ParamTree, x: jnp.ndarray, *, n_experts: int,
             else (data_axes[0] if data_axes else None))
     batch_spec = P(d_ax, model_axis if seq_shard else None, None)
     fs = fsdp_axis
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(batch_spec,
                   P(None, None),                      # router replicated

@@ -244,7 +244,8 @@ class EventServeEngine:
                  fusion_policy: Optional[str] = None,
                  donate_buffers: bool = False,
                  policy: Optional[ExecutionPolicy] = None,
-                 backend: Optional[str] = None):
+                 backend: Optional[str] = None,
+                 device: Optional[jax.Device] = None):
         """Compile the network into the engine's jitted per-window step.
 
         ``policy`` (an `repro.core.policies.ExecutionPolicy`) selects the
@@ -262,6 +263,9 @@ class EventServeEngine:
         so XLA reuses their device buffers in place — the resident slot
         state never round-trips or reallocates between windows.  Results
         are bitwise unchanged; the streaming runtime turns this on.
+        ``device`` commits the engine's weights, slot state and every
+        per-window input to one device (the mesh backend's shards); by
+        default they live on JAX's default device.
         """
         if n_slots < 1 or window < 1:
             raise ValueError("need n_slots >= 1 and window >= 1")
@@ -280,7 +284,8 @@ class EventServeEngine:
                              f"backend; policy selects {pol.backend!r}")
         self.policy = pol
         self.spec = spec
-        self.params = list(params)
+        self.device = device
+        self.params = jax.device_put(list(params), device)
         self.N = n_slots
         self.W = window
         self.dtype_policy = pol.dtype_policy
@@ -306,7 +311,8 @@ class EventServeEngine:
         L = len(spec.layers)
 
         self.states = tuple(self._zero_state(op) for op in self.program.ops)
-        self.class_counts = jnp.zeros((n_slots, spec.n_classes), jnp.float32)
+        self.class_counts = self._put(
+            np.zeros((n_slots, spec.n_classes), np.float32))
 
         # host-side slot bookkeeping (the collector's view)
         self.slot_req: List[Optional[EventRequest]] = [None] * n_slots
@@ -378,14 +384,18 @@ class EventServeEngine:
 
     # --- helpers -----------------------------------------------------------
 
+    def _put(self, x) -> jnp.ndarray:
+        """Host array -> the engine's device (JAX's default if unset)."""
+        return jax.device_put(x, self.device)
+
     def _zero_state(self, op: LayerOp) -> jnp.ndarray:
         Ho, Wo, Co = op.spec.out_shape
         h = op.halo
         # storage dtype follows the program's dtype policy: float32
         # carrier, or int8 resident membranes on the native path (4x less
         # slot state held between windows)
-        return jnp.zeros((self.N, Ho + 2 * h, Wo + 2 * h, Co),
-                         state_dtype(op))
+        return self._put(np.zeros((self.N, Ho + 2 * h, Wo + 2 * h, Co),
+                                  state_dtype(op)))
 
     def _reset_slot_state(self, slot: int) -> jnp.ndarray:
         self.states, self.class_counts, row = self._reset(
@@ -723,19 +733,19 @@ class EventServeEngine:
         if full_batch:
             states_c, cc_c = self.states, self.class_counts
         else:
-            gj = jnp.asarray(gidx)
+            gj = self._put(gidx)
             states_c = tuple(v[gj] for v in self.states)
             cc_c = self.class_counts[gj]
         states_c, cc_c, counts, drops = self._step(
-            self.params, states_c, cc_c, jnp.asarray(xyc_w),
-            jnp.asarray(gate_w), jnp.asarray(alive_w), jnp.asarray(pre))
+            self.params, states_c, cc_c, self._put(xyc_w),
+            self._put(gate_w), self._put(alive_w), self._put(pre))
         if full_batch:
             # batch position == slot index
             self.states = states_c
             self.class_counts = cc_c
         else:
             # batch position i holds slot idx[i]
-            real = jnp.asarray(idx)
+            real = self._put(idx)
             self.states = tuple(v.at[real].set(sc[:A])
                                 for v, sc in zip(self.states, states_c))
             self.class_counts = self.class_counts.at[real].set(cc_c[:A])

@@ -68,7 +68,7 @@ from repro.core.layer_program import (FUSED_NETWORK, FUSED_WINDOW,
 from repro.core.policies import (BACKEND_LOCAL, BACKEND_MESH,
                                  ExecutionPolicy, resolve_policy)
 from repro.core.sne_net import SNNSpec
-from repro.distributed.sharding import (replicated, shard_map, slot_mesh,
+from repro.distributed.sharding import (replicated, slot_mesh,
                                         slot_sharding, slot_spec)
 from repro.serve.event_engine import (CollectedWindow, EventRequest,
                                       EventServeEngine, InflightWindow,
@@ -179,10 +179,7 @@ class MeshEventServeEngine(EventServeEngine):
                 step_capacities=step_capacities, sne_cfg=sne_cfg,
                 n_parallel_slices=n_parallel_slices, co_blk=co_blk,
                 use_pallas=use_pallas, donate_buffers=donate_buffers,
-                policy=local_pol)
-            sh.states = tuple(jax.device_put(v, dev) for v in sh.states)
-            sh.class_counts = jax.device_put(sh.class_counts, dev)
-            sh.params = jax.device_put(sh.params, dev)
+                policy=local_pol, device=dev)
             self.shards.append(sh)
         self.program = self.shards[0].program
         self.caps = self.shards[0].caps
@@ -196,10 +193,10 @@ class MeshEventServeEngine(EventServeEngine):
         P1, Pw = slot_spec(1, 0), slot_spec(2, 1)   # (N,...) / (W, N, ...)
         step_fn = partial(window_step, program=self.program, co_blk=co_blk,
                           use_pallas=use_pallas)
-        # check_vma=False: outputs are all slot-sharded (nothing claimed
-        # replicated), and 0.4.x check_rep lacks rules for some scatter
-        # ops — the flag only disables an assertion layer, not numerics
-        self._mesh_step = jax.jit(shard_map(
+        # check_vma=False: every output is slot-sharded (nothing claimed
+        # replicated); the flag only disables an assertion layer, not
+        # numerics
+        self._mesh_step = jax.jit(jax.shard_map(
             step_fn, mesh=self.mesh,
             in_specs=(jax.sharding.PartitionSpec(), P1, P1, Pw, Pw, Pw, P1),
             out_specs=(P1, P1, Pw, Pw), check_vma=False))
@@ -404,8 +401,12 @@ class MeshEventServeEngine(EventServeEngine):
             for li in range(len(self.shards[0].states)))
         cc_g = self._assemble([sh.class_counts for sh in self.shards],
                               ndim=2)
+        # host inputs go straight to the device that owns each slot block
+        ins = [jax.device_put(a, slot_sharding(self.mesh, a.ndim, 1))
+               for a in (xyc, gate, alive)]
+        ins.append(jax.device_put(pre, slot_sharding(self.mesh, 1, 0)))
         states_g, cc_g, counts, drops = self._mesh_step(
-            self._mesh_params, states_g, cc_g, xyc, gate, alive, pre)
+            self._mesh_params, states_g, cc_g, *ins)
         split_states = [self._split(v) for v in states_g]
         split_cc = self._split(cc_g)
         for s, sh in enumerate(self.shards):

@@ -12,7 +12,7 @@ and everything stacked on it backpropagate correctly:
      backward = the soft primitive) — this covers the spiking/reset
      regime, where the hard forward is *not* differentiable and finite
      differences cannot apply;
-  3. central differences (float64, `jax.experimental.enable_x64`) against
+  3. central differences (float64, `jax.enable_x64`) against
      ``jax.grad`` in sub-threshold regimes where the hard forward is
      locally smooth: `lif_rollout` over membranes kept away from the
      threshold and the leak's |v|=leak kink, and the *executor's own*
@@ -29,7 +29,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -187,7 +186,7 @@ def test_rollout_fd_subthreshold(seed, T, n, leak_on, soft):
     # forward is locally smooth and central differences are valid
     p = LifParams(threshold=1.0, leak=0.0625 * leak_on,
                   reset_mode="subtract" if soft else "zero")
-    with enable_x64():
+    with jax.enable_x64(True):
         key = jax.random.PRNGKey(seed)
         syn = (0.2 + 0.05 * jax.random.uniform(key, (T, n))
                ).astype(jnp.float64)
@@ -241,7 +240,7 @@ def _fd_layer_case(spec, w, density, seed, cap=96):
 
 def test_layer_timestep_fd_conv_weights():
     # prime 5x7 geometry; |w| ~ 0.01 keeps every membrane sub-threshold
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = EConvSpec(kind="conv", in_shape=(5, 7, 2), out_channels=3,
                          kernel=3, stride=1, padding=1,
                          lif=LifParams(threshold=1.0, leak=0.0625))
@@ -252,7 +251,7 @@ def test_layer_timestep_fd_conv_weights():
 
 
 def test_layer_timestep_fd_fc_weights():
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = EConvSpec(kind="fc", in_shape=(3, 5, 2), out_channels=7,
                          lif=LifParams(threshold=1.0, leak=0.0))
         w = (0.01 * jax.random.normal(jax.random.PRNGKey(2), (30, 7))
@@ -264,7 +263,7 @@ def test_layer_timestep_fd_fc_weights():
 def test_layer_timestep_fd_pool_weights():
     # pool synapse 0.3 against th=1.0: one window never sums past 4*0.3=1.2?
     # keep density low so <=3 of 4 inputs fire per window -> max v 0.9
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = EConvSpec(kind="pool", in_shape=(6, 6, 2), out_channels=2,
                          kernel=2, stride=2,
                          lif=LifParams(threshold=1.0, leak=0.0))
