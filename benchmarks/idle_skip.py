@@ -20,9 +20,8 @@ and checks that the serving path actually honours the claim:
 A second, *spatial* sweep exercises the orthogonal axis — tile-level
 spatial sparsity plus adaptive event bucketing: cohorts whose events are
 confined to a shrinking sub-square (constant event density, every window
-active) must show measured layer-0 tile occupancy, collector launch
-bytes (the adaptive ``Eb`` ladder at work) and wall time all falling
-monotonically, bitwise equal to the ``tile_sparsity=False`` path, with
+active) must show collector launch bytes (the adaptive ``Eb`` ladder
+at work) and wall time both falling monotonically, bitwise equal to the ``tile_sparsity=False`` path, with
 ``padding_waste()`` beating the power-of-two counterfactual.
 
 Emits ``BENCH_idle_skip.json`` for CI's regression gate
@@ -96,8 +95,6 @@ def serve(eng: EventServeEngine, reqs) -> dict:
         "dense_slot_windows": eng.stats["dense_slot_windows"]
         - before["dense_slot_windows"],
         "launch_bytes": eng.stats["launch_bytes"] - before["launch_bytes"],
-        "hot_tiles": eng.stats["hot_tiles"] - before["hot_tiles"],
-        "total_tiles": eng.stats["total_tiles"] - before["total_tiles"],
         "events": agg["total_events"],
         "energy_j": agg["mean_sne_energy_j"] * agg["n_requests"],
         "events_per_joule": agg["events_per_joule"],
@@ -185,7 +182,7 @@ def make_spatial_requests(spatial_frac: float, n_requests: int,
 def spatial_sweep(spatial_fracs=(1.0, 0.5, 0.25, 0.1), n_requests: int = 4,
                   n_timesteps: int = 24, window: int = 4, use_pallas=False,
                   seed: int = 0, repeats: int = 3):
-    """Tile-sparsity sweep: launch bytes + wall vs measured occupancy."""
+    """Tile-sparsity sweep: launch bytes + wall vs the active region."""
     spec = tiny_net()
     params = init_snn(jax.random.PRNGKey(seed), spec)
 
@@ -219,7 +216,6 @@ def spatial_sweep(spatial_fracs=(1.0, 0.5, 0.25, 0.1), n_requests: int = 4,
         assert r["launch_bytes"] == d["launch_bytes"]  # same adaptive Eb
         r.update({
             "spatial_frac": frac,
-            "tile_occupancy": r["hot_tiles"] / max(r["total_tiles"], 1),
             "dense_wall_per_inf_s": min(t["wall_per_inf_s"]
                                         for t in dtrials),
         })
@@ -269,28 +265,26 @@ def main(fast: bool = False, use_pallas: bool = False) -> None:
     # --- spatial axis: tile sparsity + adaptive event bucketing ----------
     print("  spatial sweep [tile bitmaps + adaptive collector buckets]")
     srows, waste = spatial_sweep(n_timesteps=n_ts, use_pallas=use_pallas)
-    print(f"  {'frac':>5} {'occ':>5} {'events':>7} {'bytes':>9} "
+    print(f"  {'frac':>5} {'events':>7} {'bytes':>9} "
           f"{'ms/inf':>8} {'dense':>8}")
     for r in srows:
-        print(f"  {r['spatial_frac']:>5.2f} {r['tile_occupancy']:>5.2f} "
+        print(f"  {r['spatial_frac']:>5.2f} "
               f"{r['events']:>7.0f} {r['launch_bytes']:>9} "
               f"{r['wall_per_inf_s'] * 1e3:>8.2f} "
               f"{r['dense_wall_per_inf_s'] * 1e3:>8.2f}")
     s_bytes = [r["launch_bytes"] for r in srows]
     s_walls = [r["wall_per_inf_s"] for r in srows]
-    s_occ = [r["tile_occupancy"] for r in srows]
     for i in range(1, len(srows)):
-        # measured occupancy falls with the active region, bytes strictly
-        # (adaptive Eb is deterministic); wall within the jitter guard
-        assert s_occ[i] < s_occ[i - 1], s_occ
+        # bytes fall strictly with the active region (adaptive Eb is
+        # deterministic); wall within the jitter guard
         assert s_bytes[i] < s_bytes[i - 1], s_bytes
         assert s_walls[i] <= s_walls[i - 1] * 1.10, s_walls
     assert s_walls[-1] < s_walls[0], s_walls
     # adaptive bucketing must beat the pow2 counterfactual it replaced
     assert waste["padding_waste_improvement"] > 1.0, waste
     print(f"  spatial: {s_bytes[0] / s_bytes[-1]:.1f}x fewer launch bytes, "
-          f"{s_walls[0] / s_walls[-1]:.1f}x faster per inference at "
-          f"{s_occ[-1]:.0%} tile occupancy; padding waste "
+          f"{s_walls[0] / s_walls[-1]:.1f}x faster per inference; "
+          f"padding waste "
           f"{waste['padding_waste_improvement']:.2f}x better than pow2")
 
     out = {
@@ -305,7 +299,6 @@ def main(fast: bool = False, use_pallas: bool = False) -> None:
         "events_per_joule": rows[0]["events_per_joule"],
         "launch_ratio_90": hi["launch_ratio"],
         "spatial_bytes": s_bytes,
-        "tile_occupancy": s_occ,
         "padding_waste_improvement": waste["padding_waste_improvement"],
     }
     with open("BENCH_idle_skip.json", "w") as f:

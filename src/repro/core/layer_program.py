@@ -658,6 +658,12 @@ def window_tile_maps(program: LayerProgram, ev_xyc: jnp.ndarray,
     return tuple(tiles)
 
 
+def window_kernel_name(op: LayerOp) -> str:
+    """The name of ``op``'s fused-window launch: its layer index and kind
+    (``layer2_pool_window``), stable across slot and event buckets."""
+    return f"layer{op.index}_{op.spec.kind}_window"
+
+
 def layer_window(op: LayerOp, params: EConvParams, vp: jnp.ndarray,
                  xyc: jnp.ndarray, gate: jnp.ndarray, alive: jnp.ndarray,
                  co_blk: int = 128, use_pallas: Optional[bool] = None,
@@ -690,10 +696,13 @@ def layer_window(op: LayerOp, params: EConvParams, vp: jnp.ndarray,
 
     Returns ``(vp_new, spikes (T, N, Ho, Wo, C))`` with spikes in the
     op's accumulator dtype (what :func:`frame_to_events` routes onward).
+    The launch is named ``layer<index>_<kind>_window`` in the compiled
+    program and the profiler trace (:func:`window_kernel_name`).
     """
     spec = op.spec
     check_native_weights(op, params)
     native = op.dtype_policy == INT8_NATIVE
+    name = window_kernel_name(op)
     x = jnp.transpose(xyc, (1, 0, 2, 3))     # slot-major for the kernels
     g = jnp.transpose(gate, (1, 0, 2))
     a = jnp.transpose(alive, (1, 0))
@@ -702,16 +711,17 @@ def layer_window(op: LayerOp, params: EConvParams, vp: jnp.ndarray,
         vp_new, s = event_conv_window(
             vp, params.w, x + off, g, a, lif=op.lif, halo=op.halo,
             co_blk=_channel_block(spec.out_channels, co_blk), native=native,
-            use_pallas=use_pallas, tiles=tiles)
+            use_pallas=use_pallas, tiles=tiles, name=name)
     elif spec.kind == "pool":
         vp_new, s = event_pool_window(vp, params.w, x, g, a, lif=op.lif,
                                       stride=spec.stride, native=native,
-                                      use_pallas=use_pallas, tiles=tiles)
+                                      use_pallas=use_pallas, tiles=tiles,
+                                      name=name)
     else:
         vp_new, s = event_fc_window(
             vp, params.w, x, g, a, lif=op.lif, in_shape=spec.in_shape,
             d_blk=_channel_block(spec.out_channels, co_blk), native=native,
-            use_pallas=use_pallas)
+            use_pallas=use_pallas, name=name)
     return vp_new, jnp.transpose(s, (1, 0, 2, 3, 4))
 
 

@@ -34,6 +34,7 @@ a whole receptive-field column per event.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -214,12 +215,13 @@ def _event_conv_window_kernel(alive_ref, tiles_ref, ev_ref, w_ref, v_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("lif", "halo", "co_blk",
-                                             "native", "interpret"))
+                                             "native", "interpret", "name"))
 def event_conv_window_pallas(v: jnp.ndarray, weights: jnp.ndarray,
                              ev_xyc: jnp.ndarray, ev_gate: jnp.ndarray,
                              alive: jnp.ndarray, tiles: jnp.ndarray, *,
                              lif: LifParams, halo: int, co_blk: int = 128,
-                             native: bool = False, interpret: bool = False):
+                             native: bool = False, interpret: bool = False,
+                             name: Optional[str] = None):
     """Advance N slots through a whole T-timestep window in ONE launch.
 
     The fused window form of :func:`event_conv_batched_pallas`: instead of
@@ -245,6 +247,9 @@ def event_conv_window_pallas(v: jnp.ndarray, weights: jnp.ndarray,
       co_blk:  output-channel block size (must divide Co).
       native:  int8-native policy — int32 accumulator, int8 saturation at
                every boundary, int8 storage out.
+      name:    the launch's name in the compiled program and the
+               profiler trace (``layer2_pool_window``); None keeps
+               this function's name.
 
     Returns ``(v_out (N, Hp, Wp, Co) storage dtype,
     spikes (N, T, Ho, Wo, Co) accumulator dtype)``.
@@ -298,5 +303,6 @@ def event_conv_window_pallas(v: jnp.ndarray, weights: jnp.ndarray,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
+        name=name,
     )((alive > 0).astype(jnp.int32), tiles.astype(jnp.int32).reshape(-1),
       words, w_f, v)

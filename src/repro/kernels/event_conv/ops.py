@@ -70,7 +70,8 @@ def event_conv_window(v: jnp.ndarray, weights: jnp.ndarray,
                       alive: jnp.ndarray, *, lif, halo: int,
                       co_blk: int = 128, native: bool = False,
                       use_pallas: bool | None = None,
-                      tiles: jnp.ndarray | None = None):
+                      tiles: jnp.ndarray | None = None,
+                      name: str | None = None):
     """Advance N slots through a whole T-timestep window in ONE launch.
 
     The fused window entry point (``fusion_policy="fused-window"``): the
@@ -85,6 +86,7 @@ def event_conv_window(v: jnp.ndarray, weights: jnp.ndarray,
     leak/clip/fire sweeps and settle with one analytic decay.  Only
     hard-reset layers (`supports_idle_skip`) may pass one — the deferred
     decay has no closed form under soft reset.  ``None`` runs dense.
+    ``name`` names the Pallas launch (see `event_conv_window_pallas`).
 
     A zero-length event axis still runs the window (leak/fire must
     advance, unlike the scatter-only kernels) — the schedule is padded to
@@ -107,4 +109,4 @@ def event_conv_window(v: jnp.ndarray, weights: jnp.ndarray,
     return event_conv_window_pallas(v, weights, ev_xyc, ev_gate, alive,
                                     tiles, lif=lif, halo=halo,
                                     co_blk=co_blk, native=native,
-                                    interpret=not _on_tpu())
+                                    interpret=not _on_tpu(), name=name)

@@ -26,7 +26,7 @@ exactly the reference oracle's, so results are bit-for-bit equal to
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -198,12 +198,13 @@ def _event_fc_window_kernel(alive_ref, ev_ref, w_ref, v_ref, v_out_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("lif", "in_shape", "d_blk",
-                                             "native", "interpret"))
+                                             "native", "interpret", "name"))
 def event_fc_window_pallas(v: jnp.ndarray, w: jnp.ndarray,
                            ev_xyc: jnp.ndarray, ev_gate: jnp.ndarray,
                            alive: jnp.ndarray, *, lif: LifParams,
                            in_shape: Tuple[int, int, int], d_blk: int = 128,
-                           native: bool = False, interpret: bool = False):
+                           native: bool = False, interpret: bool = False,
+                           name: Optional[str] = None):
     """Advance N slots through a whole T-timestep FC window in ONE launch.
 
     The fused window form of :func:`event_fc_batched_pallas`; results are
@@ -219,6 +220,9 @@ def event_fc_window_pallas(v: jnp.ndarray, w: jnp.ndarray,
       in_shape: (H, W, C) static input geometry (flattening rule).
       d_blk:    output-block size (must divide Dout).
       native:   int8-native policy switch.
+      name:     the launch's name in the compiled program and the
+                profiler trace (``layer2_pool_window``); None keeps
+                this function's name.
 
     Returns ``(v_out (N, 1, 1, Dout) storage dtype,
     spikes (N, T, 1, 1, Dout) accumulator dtype)``.
@@ -263,4 +267,5 @@ def event_fc_window_pallas(v: jnp.ndarray, w: jnp.ndarray,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
+        name=name,
     )((alive > 0).astype(jnp.int32), words, w, v)

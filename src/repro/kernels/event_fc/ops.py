@@ -71,7 +71,8 @@ def event_fc_batched(v: jnp.ndarray, w: jnp.ndarray, ev_xyc: jnp.ndarray,
 def event_fc_window(v: jnp.ndarray, w: jnp.ndarray, ev_xyc: jnp.ndarray,
                     ev_gate: jnp.ndarray, alive: jnp.ndarray, *, lif,
                     in_shape: Tuple[int, int, int], d_blk: int = 128,
-                    native: bool = False, use_pallas: bool | None = None):
+                    native: bool = False, use_pallas: bool | None = None,
+                    name: str | None = None):
     """Advance N slots through a whole T-timestep FC window in ONE launch.
 
     The fused window entry point (``fusion_policy="fused-window"``) —
@@ -81,7 +82,8 @@ def event_fc_window(v: jnp.ndarray, w: jnp.ndarray, ev_xyc: jnp.ndarray,
     ``(v_out, spikes)`` with spikes shaped ``(N, T, 1, 1, Dout)``.
 
     A zero-length event axis still runs the window (leak/fire must
-    advance) — the schedule is padded to one gated-off event.
+    advance) — the schedule is padded to one gated-off event.  ``name``
+    names the Pallas launch (see `event_fc_window_pallas`).
     """
     ev_xyc, ev_gate = pad_empty_schedule(ev_xyc, ev_gate)
     if use_pallas is False:
@@ -89,4 +91,5 @@ def event_fc_window(v: jnp.ndarray, w: jnp.ndarray, ev_xyc: jnp.ndarray,
                                    in_shape=in_shape, native=native)
     return event_fc_window_pallas(v, w, ev_xyc, ev_gate, alive, lif=lif,
                                   in_shape=in_shape, d_blk=d_blk,
-                                  native=native, interpret=not _on_tpu())
+                                  native=native, interpret=not _on_tpu(),
+                                  name=name)

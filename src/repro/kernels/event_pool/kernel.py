@@ -26,6 +26,7 @@ exactly the reference oracle's, so results are bit-for-bit equal to
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -209,12 +210,13 @@ def _event_pool_window_kernel(alive_ref, tiles_ref, ev_ref, w_ref, v_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("lif", "stride", "native",
-                                             "interpret"))
+                                             "interpret", "name"))
 def event_pool_window_pallas(v: jnp.ndarray, w: jnp.ndarray,
                              ev_xyc: jnp.ndarray, ev_gate: jnp.ndarray,
                              alive: jnp.ndarray, tiles: jnp.ndarray, *,
                              lif: LifParams, stride: int,
-                             native: bool = False, interpret: bool = False):
+                             native: bool = False, interpret: bool = False,
+                             name: Optional[str] = None):
     """Advance N slots through a whole T-timestep pool window in ONE launch.
 
     The fused window form of :func:`event_pool_batched_pallas`; results
@@ -231,6 +233,9 @@ def event_pool_window_pallas(v: jnp.ndarray, w: jnp.ndarray,
       lif:     the layer's LIF plan (static).
       stride:  pooling stride.
       native:  int8-native policy switch.
+      name:    the launch's name in the compiled program and the
+               profiler trace (``layer2_pool_window``); None keeps
+               this function's name.
 
     Returns ``(v_out (N, Ho, Wo, C) storage dtype,
     spikes (N, T, Ho, Wo, C) accumulator dtype)``.
@@ -277,5 +282,6 @@ def event_pool_window_pallas(v: jnp.ndarray, w: jnp.ndarray,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
+        name=name,
     )((alive > 0).astype(jnp.int32), tiles.astype(jnp.int32).reshape(-1),
       words, _pool_weights(w, v), v)

@@ -70,7 +70,8 @@ def event_pool_window(v: jnp.ndarray, w: jnp.ndarray, ev_xyc: jnp.ndarray,
                       ev_gate: jnp.ndarray, alive: jnp.ndarray, *, lif,
                       stride: int, native: bool = False,
                       use_pallas: bool | None = None,
-                      tiles: jnp.ndarray | None = None):
+                      tiles: jnp.ndarray | None = None,
+                      name: str | None = None):
     """Advance N slots through a whole T-timestep pool window in ONE launch.
 
     The fused window entry point (``fusion_policy="fused-window"``) —
@@ -82,7 +83,8 @@ def event_pool_window(v: jnp.ndarray, w: jnp.ndarray, ev_xyc: jnp.ndarray,
     ``tiles`` is an optional (N, nTx, nTy) activity bitmap over (Ho, Wo)
     (`window_common.tile_grid` geometry): cold tiles skip the per-timestep
     sweeps and settle with one analytic decay.  Hard-reset layers only;
-    ``None`` runs dense.
+    ``None`` runs dense.  ``name`` names the Pallas launch (see
+    `event_pool_window_pallas`).
 
     A zero-length event axis still runs the window (leak/fire must
     advance) — the schedule is padded to one gated-off event.
@@ -103,4 +105,4 @@ def event_pool_window(v: jnp.ndarray, w: jnp.ndarray, ev_xyc: jnp.ndarray,
         tiles = jnp.ones((v.shape[0], nTx, nTy), jnp.int32)
     return event_pool_window_pallas(v, w, ev_xyc, ev_gate, alive, tiles,
                                     lif=lif, stride=stride, native=native,
-                                    interpret=not _on_tpu())
+                                    interpret=not _on_tpu(), name=name)

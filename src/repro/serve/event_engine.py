@@ -71,7 +71,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from functools import lru_cache, partial
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -87,10 +87,10 @@ from repro.core.layer_program import (FUSED_NETWORK, FUSED_WINDOW, LayerOp,
 from repro.core.layer_program import \
     default_step_capacities as _program_step_capacities
 from repro.core.lif import supports_idle_skip
-from repro.kernels.window_common import tile_grid
 from repro.core.policies import (BACKEND_LOCAL, BACKEND_MESH,
                                  ExecutionPolicy, resolve_policy)
 from repro.core.sne_net import SNNSpec
+from repro.serve.spans import Span
 from repro.serve.telemetry import RequestTelemetry, request_telemetry
 
 
@@ -143,6 +143,7 @@ class CollectedWindow:
     n_win_ev: np.ndarray   # (N,) int64 raw events per slot this window
     max_bucket: int        # largest (slot, timestep) bucket fill
     part_idx: np.ndarray   # participating slot indices
+    seq: int               # the engine's window number (``n_collected``)
 
 
 @dataclasses.dataclass
@@ -160,6 +161,7 @@ class InflightWindow:
     full_batch: bool       # batch position == slot index (no compaction)
     counts: jnp.ndarray    # (L, batch) per-layer consumed events — future
     drops: jnp.ndarray     # (L, batch) inter-layer overflow — future
+    seq: int               # the collected window's number
 
 
 def default_step_capacities(spec: SNNSpec, activity: float = 0.25,
@@ -310,10 +312,6 @@ class EventServeEngine:
             supports_idle_skip(l.lif) for l in spec.layers)
         L = len(spec.layers)
 
-        self.states = tuple(self._zero_state(op) for op in self.program.ops)
-        self.class_counts = self._put(
-            np.zeros((n_slots, spec.n_classes), np.float32))
-
         # host-side slot bookkeeping (the collector's view)
         self.slot_req: List[Optional[EventRequest]] = [None] * n_slots
         self.active = np.zeros((n_slots,), bool)
@@ -348,12 +346,19 @@ class EventServeEngine:
                       "collected_events": 0, "launched_events": 0,
                       "padded_event_slots": 0, "padded_event_slots_pow2": 0,
                       "launch_bytes": 0,
-                      # measured input tile occupancy: hot tiles in the
-                      # layer-0 tile grid per launched (slot, window), vs
-                      # the grid size — the workload's spatial sparsity as
-                      # the tile-sparse kernels see it
-                      "hot_tiles": 0, "total_tiles": 0}
-        self._tile_grid0 = tile_grid(*spec.in_shape[:2])
+                      # every host byte handed to the device, counted at
+                      # the put itself (`_put`)
+                      "h2d_bytes": 0}
+        # host seconds per span name (`repro.serve.spans`); a streaming
+        # runtime reports this dict as its ``phase_s``
+        self.phase_s: Dict[str, float] = {}
+        self.n_collected = 0     # windows collected; the next one's number
+
+        # zeroed slot state on the device (its bytes count in h2d_bytes)
+        self.states = tuple(self._zero_state(op) for op in self.program.ops)
+        self.class_counts = self._put(
+            np.zeros((n_slots, spec.n_classes), np.float32))
+
         # histogram of per-(slot, timestep) bucket occupancy: bin 0 holds
         # empty buckets, bin b>0 holds fills whose power-of-two ceiling is
         # 2^(b-1) — the measured baseline for adaptive event-capacity
@@ -384,8 +389,10 @@ class EventServeEngine:
 
     # --- helpers -----------------------------------------------------------
 
-    def _put(self, x) -> jnp.ndarray:
-        """Host array -> the engine's device (JAX's default if unset)."""
+    def _put(self, x: np.ndarray) -> jnp.ndarray:
+        """Host array -> the engine's device (JAX's default if unset),
+        counted in ``stats["h2d_bytes"]``."""
+        self.stats["h2d_bytes"] += x.nbytes
         return jax.device_put(x, self.device)
 
     def _zero_state(self, op: LayerOp) -> jnp.ndarray:
@@ -399,7 +406,7 @@ class EventServeEngine:
 
     def _reset_slot_state(self, slot: int) -> jnp.ndarray:
         self.states, self.class_counts, row = self._reset(
-            self.states, self.class_counts, slot)
+            self.states, self.class_counts, self._put(np.int32(slot)))
         return row
 
     @property
@@ -514,9 +521,10 @@ class EventServeEngine:
             return None
         xyc, gate, alive, n_win_ev, max_bucket = \
             self._collect_window(part_idx)
+        self.n_collected += 1
         return CollectedWindow(xyc=xyc, gate=gate, alive=alive,
                                n_win_ev=n_win_ev, max_bucket=max_bucket,
-                               part_idx=part_idx)
+                               part_idx=part_idx, seq=self.n_collected - 1)
 
     def _collect_window(self, part_idx: np.ndarray):
         """Bin each participating slot's next ``W`` timesteps of events.
@@ -627,7 +635,8 @@ class EventServeEngine:
         inflight = None
         if len(dense_idx):
             inflight = self._launch_window(dense_idx, col.xyc, col.gate,
-                                           col.alive, col.max_bucket)
+                                           col.alive, col.max_bucket,
+                                           col.seq)
         return inflight, self._account_window(col, dense_idx)
 
     def _select_dense(self, col: CollectedWindow) -> np.ndarray:
@@ -678,7 +687,7 @@ class EventServeEngine:
 
     def _launch_window(self, idx: np.ndarray, xyc: np.ndarray,
                        gate: np.ndarray, alive: np.ndarray,
-                       max_bucket: int) -> InflightWindow:
+                       max_bucket: int, seq: int) -> InflightWindow:
         """Compact the active slots and dispatch the batched window step.
 
         Without ``idle_skip`` this degenerates to the original full-batch
@@ -757,15 +766,6 @@ class EventServeEngine:
         self.stats["padded_event_slots_pow2"] += self.W * len(gidx) * Eb_pow2
         self.stats["launch_bytes"] += (xyc_w.nbytes + gate_w.nbytes
                                        + alive_w.nbytes)
-        # measured input tile occupancy over the REAL slots (dummy tail
-        # positions mirror slot 0 and would double-count its footprint)
-        nTx, nTy, th, tw = self._tile_grid0
-        hot = np.zeros((A, nTx, nTy), bool)
-        t_, s_, e_ = np.nonzero(gate_w[:, :A] > 0)
-        hot[s_, np.minimum(xyc_w[t_, s_, e_, 0] // th, nTx - 1),
-            np.minimum(xyc_w[t_, s_, e_, 1] // tw, nTy - 1)] = True
-        self.stats["hot_tiles"] += int(hot.sum())
-        self.stats["total_tiles"] += A * nTx * nTy
         # fused-network: ONE launch for the whole window (or per-layer
         # fused-window launches when the VMEM budget forced a fallback —
         # effective_fusion is the same predicate the driver uses);
@@ -779,7 +779,7 @@ class EventServeEngine:
         else:
             self.stats["kernel_launches"] += self.W * len(self.program.ops)
         return InflightWindow(idx=idx, n_compact=A, full_batch=full_batch,
-                              counts=counts, drops=drops)
+                              counts=counts, drops=drops, seq=seq)
 
     def _retire_phase(self, w: InflightWindow) -> None:
         """Block on one in-flight window and apply its numpy accounting.
@@ -789,8 +789,9 @@ class EventServeEngine:
         which is why a finished slot may only be released
         (:meth:`_finish`) after its last window retires.
         """
-        counts_np = np.asarray(w.counts, np.float64)
-        drops_np = np.asarray(w.drops, np.float64)
+        with Span(self.phase_s, "serve.retire.wait", win=w.seq):
+            counts_np = np.asarray(w.counts, np.float64)
+            drops_np = np.asarray(w.drops, np.float64)
         idx, A = w.idx, w.n_compact
         if w.full_batch:
             self.acc_counts[:, idx] += counts_np[:, idx]

@@ -55,7 +55,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +73,7 @@ from repro.distributed.sharding import (replicated, slot_mesh,
 from repro.serve.event_engine import (CollectedWindow, EventRequest,
                                       EventServeEngine, InflightWindow,
                                       event_bucket)
+from repro.serve.spans import Span
 
 
 @dataclasses.dataclass
@@ -87,6 +88,7 @@ class MeshCollectedWindow:
 
     cols: List[Optional[CollectedWindow]]
     part_idx: np.ndarray
+    seq: int                      # the mesh's window number
 
 
 @dataclasses.dataclass
@@ -101,6 +103,7 @@ class MeshInflightWindow:
     """
 
     idx: np.ndarray
+    seq: int
     per_shard: Optional[List[Tuple[int, InflightWindow]]] = None
     dense: Optional[List[np.ndarray]] = None
     counts: Optional[jnp.ndarray] = None
@@ -181,6 +184,12 @@ class MeshEventServeEngine(EventServeEngine):
                 use_pallas=use_pallas, donate_buffers=donate_buffers,
                 policy=local_pol, device=dev)
             self.shards.append(sh)
+        # one span record for the mesh and its shards (their retire waits
+        # run inside the mesh's retire)
+        self.phase_s: Dict[str, float] = {}
+        for sh in self.shards:
+            sh.phase_s = self.phase_s
+        self.n_collected = 0
         self.program = self.shards[0].program
         self.caps = self.shards[0].caps
         self.idle_skip = self.shards[0].idle_skip
@@ -206,7 +215,8 @@ class MeshEventServeEngine(EventServeEngine):
         self._extra = {"windows": 0, "step_calls": 0, "kernel_launches": 0,
                        "launched_events": 0, "padded_event_slots": 0,
                        "padded_event_slots_pow2": 0, "launch_bytes": 0,
-                       "mesh_global_windows": 0, "mesh_shard_windows": 0}
+                       "h2d_bytes": 0, "mesh_global_windows": 0,
+                       "mesh_shard_windows": 0}
 
         # one-time sanity probe: the zero-copy assembly of per-device
         # blocks must map shard s to global rows [s*spd, (s+1)*spd)
@@ -224,6 +234,12 @@ class MeshEventServeEngine(EventServeEngine):
         shape = (self.N,) + tuple(pieces[0].shape[1:])
         return jax.make_array_from_single_device_arrays(
             shape, slot_sharding(self.mesh, ndim, 0), pieces)
+
+    def _put_slots(self, a: np.ndarray, axis: int) -> jnp.ndarray:
+        """Host array -> its slot blocks on their devices (slot axis
+        ``axis``), counted in ``h2d_bytes``."""
+        self._extra["h2d_bytes"] += a.nbytes
+        return jax.device_put(a, slot_sharding(self.mesh, a.ndim, axis))
 
     def _split(self, garr: jnp.ndarray) -> List[jnp.ndarray]:
         """Per-shard device-local blocks of a slot-sharded global array."""
@@ -314,7 +330,12 @@ class MeshEventServeEngine(EventServeEngine):
         part = np.concatenate(
             [self.spd * s + c.part_idx
              for s, c in enumerate(cols) if c is not None])
-        return MeshCollectedWindow(cols=cols, part_idx=part)
+        seq = self.n_collected
+        self.n_collected += 1
+        for c in cols:
+            if c is not None:
+                c.seq = seq     # shard windows carry the mesh's number
+        return MeshCollectedWindow(cols=cols, part_idx=part, seq=seq)
 
     def _launch_phase(self, col: MeshCollectedWindow
                       ) -> Tuple[Optional[MeshInflightWindow], List[int]]:
@@ -354,7 +375,8 @@ class MeshEventServeEngine(EventServeEngine):
         if not pers:
             return None, finished
         return MeshInflightWindow(
-            idx=np.concatenate(idx_parts), per_shard=pers), finished
+            idx=np.concatenate(idx_parts), seq=col.seq,
+            per_shard=pers), finished
 
     def _launch_global(self, cols: List[CollectedWindow],
                        dense: List[np.ndarray]) -> MeshInflightWindow:
@@ -402,9 +424,8 @@ class MeshEventServeEngine(EventServeEngine):
         cc_g = self._assemble([sh.class_counts for sh in self.shards],
                               ndim=2)
         # host inputs go straight to the device that owns each slot block
-        ins = [jax.device_put(a, slot_sharding(self.mesh, a.ndim, 1))
-               for a in (xyc, gate, alive)]
-        ins.append(jax.device_put(pre, slot_sharding(self.mesh, 1, 0)))
+        ins = [self._put_slots(a, 1) for a in (xyc, gate, alive)]
+        ins.append(self._put_slots(pre, 0))
         states_g, cc_g, counts, drops = self._mesh_step(
             self._mesh_params, states_g, cc_g, *ins)
         split_states = [self._split(v) for v in states_g]
@@ -426,14 +447,15 @@ class MeshEventServeEngine(EventServeEngine):
         self._extra["launch_bytes"] += xyc.nbytes + gate.nbytes + alive.nbytes
         self._extra["mesh_global_windows"] += 1
         idx = np.concatenate([n * s + d for s, d in enumerate(dense)])
-        return MeshInflightWindow(idx=idx, dense=dense,
+        return MeshInflightWindow(idx=idx, seq=cols[0].seq, dense=dense,
                                   counts=counts, drops=drops)
 
     def _retire_phase(self, w: MeshInflightWindow) -> None:
         """Block on one in-flight mesh window; apply per-shard accounting."""
         if w.counts is not None:        # fused mesh step
-            counts_np = np.asarray(w.counts, np.float64)
-            drops_np = np.asarray(w.drops, np.float64)
+            with Span(self.phase_s, "serve.retire.wait", win=w.seq):
+                counts_np = np.asarray(w.counts, np.float64)
+                drops_np = np.asarray(w.drops, np.float64)
             for s, (sh, d) in enumerate(zip(self.shards, w.dense)):
                 sh.acc_counts[:, d] += counts_np[:, self.spd * s + d]
                 sh.acc_drops[:, d] += drops_np[:, self.spd * s + d]

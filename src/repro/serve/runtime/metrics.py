@@ -52,6 +52,23 @@ class StreamingMetrics:
     queue_depth_samples: List[int] = dataclasses.field(default_factory=list)
     events_served: int = 0       # raw input events collected into windows
     span_s: float = 0.0          # serve-loop clock span
+    # host seconds per span name (`repro.serve.spans`), shared with the
+    # engine, whose retire waits add to it
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # the longest tick so far: its number, seconds, and the seconds each
+    # span name took inside it (nested spans are counted in their parents)
+    slowest_tick: Dict = dataclasses.field(default_factory=dict)
+
+    def note_tick(self, tick: int, seconds: float,
+                  before: Dict[str, float]) -> None:
+        """Keep tick ``tick`` as the slowest if it is; ``before`` is
+        ``phase_s`` as it stood when the tick began."""
+        if seconds <= self.slowest_tick.get("tick_s", -1.0):
+            return
+        split = {k: v - before.get(k, 0.0) for k, v in self.phase_s.items()
+                 if k != "serve.tick" and v != before.get(k, 0.0)}
+        self.slowest_tick = {"tick": tick, "tick_s": seconds,
+                             "phase_s": split}
 
     def summary(self, requests: Sequence[StreamRequest] = ()) -> Dict:
         """Aggregate into the serving-level report.
@@ -88,4 +105,6 @@ class StreamingMetrics:
             "events_served": self.events_served,
             "sustained_events_per_s": (self.events_served / self.span_s
                                        if self.span_s > 0 else 0.0),
+            "phase_s": dict(self.phase_s),
+            "slowest_tick": dict(self.slowest_tick),
         }

@@ -33,6 +33,16 @@ and mid-service eviction, and a pluggable slot-placement policy.  All
 timing flows through an injected clock (`repro.serve.runtime.clock`), so
 the same loop serves open-loop Poisson load against wall time and runs
 deterministically under tests.
+
+Every phase of a tick runs inside a ``serve.*`` host span
+(`repro.serve.spans`): ``serve.tick`` around the whole tick, inside it
+``serve.intake``, one ``serve.admit`` per admission, ``serve.collect``,
+``serve.launch``, ``serve.retire`` (holding the engine's
+``serve.retire.wait`` and one ``serve.finish`` per completed request) and
+``serve.wait_arrival``.  ``win`` ties a window's collect and launch to
+its retire a tick later, ``uid`` a request's admit to its finish.  The
+spans reach the profiler's trace while a session runs; their seconds
+always reach ``report()["phase_s"]`` and ``report()["slowest_tick"]``.
 """
 from __future__ import annotations
 
@@ -50,6 +60,7 @@ from repro.serve.runtime.admission import (DONE, EVICTED, REJECTED, RUNNING,
 from repro.serve.runtime.clock import WallClock
 from repro.serve.runtime.loadgen import PoissonLoadGen
 from repro.serve.runtime.metrics import StreamingMetrics
+from repro.serve.spans import Span
 
 
 @dataclasses.dataclass
@@ -103,7 +114,8 @@ class StreamingRuntime:
         self.queue = AdmissionQueue(queue_capacity)
         self.slot_policy = slot_policy
         self.clock = clock if clock is not None else WallClock()
-        self.metrics = StreamingMetrics()
+        self.metrics = StreamingMetrics(phase_s=engine.phase_s)
+        self.n_ticks = 0
         self.requests: List[StreamRequest] = []   # every request ever seen
         self.running: Dict[int, StreamRequest] = {}
         self.slot_load = np.zeros((engine.N,), np.float64)
@@ -148,12 +160,24 @@ class StreamingRuntime:
         in-flight device window), then retire the in-flight window (the
         only device sync).
         """
-        now = self.clock.now()
-        if loadgen is not None:
-            for sreq in loadgen.due(now):
-                self._ingest(sreq, now)
-        self.metrics.expired_in_queue += len(self.queue.expire(now))
-        self._evict_deadline_missed(now)
+        phase_s = self.metrics.phase_s
+        before = dict(phase_s)
+        with Span(phase_s, "serve.tick", tick=self.n_ticks) as span:
+            busy = self._advance(loadgen)
+        self.metrics.note_tick(self.n_ticks, span.seconds, before)
+        self.n_ticks += 1
+        return busy
+
+    def _advance(self, loadgen: Optional[PoissonLoadGen]) -> bool:
+        """The phases of one tick (see :meth:`tick`)."""
+        phase_s = self.metrics.phase_s
+        with Span(phase_s, "serve.intake"):
+            now = self.clock.now()
+            if loadgen is not None:
+                for sreq in loadgen.due(now):
+                    self._ingest(sreq, now)
+            self.metrics.expired_in_queue += len(self.queue.expire(now))
+            self._evict_deadline_missed(now)
         self._admit(now)
         self.metrics.queue_depth_samples.append(len(self.queue))
 
@@ -162,10 +186,14 @@ class StreamingRuntime:
         # back-to-back while the host does the retire conversion and
         # bookkeeping for k.  Collection precedes the retire either way,
         # so dispatching early costs no slot occupancy.
-        col = self.engine._collect_phase()     # overlaps device compute
+        seq = self.engine.n_collected
+        with Span(phase_s, "serve.collect", win=seq):
+            col = self.engine._collect_phase()  # overlaps device compute
         launched = None
         if col is not None:
-            win, finished = self.engine._launch_phase(col)
+            with Span(phase_s, "serve.launch", win=seq,
+                      busy=len(col.part_idx)):
+                win, finished = self.engine._launch_phase(col)
             launched = _Pending(
                 win=win, finished=finished, t_launch=self.clock.now(),
                 slot_reqs={int(s): self.running[int(s)]
@@ -191,7 +219,8 @@ class StreamingRuntime:
             # drained ahead of the arrival process: wait for the next one
             nxt = loadgen.next_arrival_s()
             if nxt is not None:
-                self.clock.wait_until(nxt)
+                with Span(phase_s, "serve.wait_arrival"):
+                    self.clock.wait_until(nxt)
         return True
 
     def serve(self, loadgen: Optional[PoissonLoadGen] = None,
@@ -267,7 +296,9 @@ class StreamingRuntime:
             slot = choose_slot(self.slot_policy, free, self.slot_load)
             sreq = self.queue.pop()
             try:
-                self.engine.try_admit(sreq.req, slot=slot)
+                with Span(self.metrics.phase_s, "serve.admit",
+                          uid=sreq.req.uid, slot=int(slot)):
+                    self.engine.try_admit(sreq.req, slot=slot)
             except ValueError:
                 # malformed stream: mark it rejected instead of crashing
                 # the serve loop (it stays visible in self.requests)
@@ -287,26 +318,31 @@ class StreamingRuntime:
         if self._inflight is None:
             return
         p = self._inflight
-        self.engine._retire_phase(p.win)       # blocks until device done
-        now = self.clock.now()
-        lat = now - p.t_launch
-        self.metrics.window_latencies_s.append(lat)
-        for slot in p.win.idx:
-            # launch-time attribution: the requests this window actually
-            # served, not whatever occupies the slot at retire time
-            sreq = p.slot_reqs.get(int(slot))
-            if sreq is not None:
-                sreq.window_latencies_s.append(lat)
-        self._finish_slots(p.finished)
+        with Span(self.metrics.phase_s, "serve.retire", win=p.win.seq):
+            self.engine._retire_phase(p.win)   # blocks until device done
+            now = self.clock.now()
+            lat = now - p.t_launch
+            self.metrics.window_latencies_s.append(lat)
+            for slot in p.win.idx:
+                # launch-time attribution: the requests this window
+                # actually served, not whatever occupies the slot at
+                # retire time
+                sreq = p.slot_reqs.get(int(slot))
+                if sreq is not None:
+                    sreq.window_latencies_s.append(lat)
+            self._finish_slots(p.finished)
         self._inflight = None
 
     def _finish_slots(self, finished: Sequence[int]) -> None:
         """Complete and release slots whose last window has retired."""
         for slot in finished:
-            if self.engine.slot_req[slot] is None:
+            req = self.engine.slot_req[slot]
+            if req is None:
                 continue                       # evicted while in flight
             self.slot_load[slot] += float(self.engine.windows[slot])
-            self.engine._finish(slot)
+            with Span(self.metrics.phase_s, "serve.finish", uid=req.uid,
+                      slot=int(slot)):
+                self.engine._finish(slot)   # reads the class counts back
             sreq = self.running.pop(slot, None)
             if sreq is not None:
                 sreq.status = DONE

@@ -17,6 +17,7 @@ runs this file loads the TPU library.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -141,4 +142,12 @@ def test_window_step_compiles_for_v5e(one_chip, monkeypatch):
     pre = _shape(sh, (N_SLOTS,), jnp.int32)
     step = functools.partial(lp.window_step, program=program)
     compiled = _compile(step, params, states, cc, xyc, gate, alive, pre)
-    assert compiled.as_text().count("tpu_custom_call") >= len(program.ops)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= len(program.ops)
+    # each layer's launch carries its own stable name, once
+    for op in program.ops:
+        name = lp.window_kernel_name(op)
+        launch = re.compile(rf"^\s*(ROOT )?%{name}(\.\d+)? = .*"
+                            rf'custom_call_target="tpu_custom_call"')
+        assert sum(bool(launch.match(line))
+                   for line in text.splitlines()) == 1, name
