@@ -144,6 +144,7 @@ def kernel_calls(engine) -> int:
     W, N, E0 = engine.W, engine.N, engine.caps[0]
     args = (jax.tree.map(sds, engine.params),
             tuple(sds(v) for v in engine.states), sds(engine.class_counts),
+            jax.ShapeDtypeStruct((2, N), jnp.int32),
             jax.ShapeDtypeStruct((W, N, E0, 3), jnp.int32),
             jax.ShapeDtypeStruct((W, N, E0), jnp.float32),
             jax.ShapeDtypeStruct((W, N), jnp.float32),

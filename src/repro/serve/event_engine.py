@@ -156,9 +156,8 @@ class InflightWindow:
     streaming runtime collect the next window while this one computes.
     """
 
-    idx: np.ndarray        # dense (launched) slot indices
-    n_compact: int         # real batch rows (the rest are dummy tail)
-    full_batch: bool       # batch position == slot index (no compaction)
+    idx: np.ndarray        # dense (launched) slot indices; batch row i
+                           # holds slot idx[i], the rest are the tail
     counts: jnp.ndarray    # (L, batch) per-layer consumed events — future
     drops: jnp.ndarray     # (L, batch) inter-layer overflow — future
     seq: int               # the collected window's number
@@ -210,6 +209,30 @@ def event_bucket(n: int, cap: int) -> int:
         if v >= n:
             return v
     return cap
+
+
+def batched_window_step(params, states, class_counts, sel, ev_xyc, ev_gate,
+                        alive, pre_dt, **kw):
+    """One window over a slot batch gathered from the resident slabs.
+
+    ``sel`` is ``(2, Ab)`` int32: row 0 the slot each batch position
+    reads (``gidx``), row 1 the slot it writes back (``sidx``).  Dummy
+    positions read a real slot and write to the out-of-range index ``N``,
+    which ``mode="drop"`` discards, so no slot is ever scattered twice.
+    Returns the full ``(N, ...)`` slabs and class counts with the batch's
+    rows written back, plus ``window_step``'s per-layer counts and drops
+    in batch order.  ``kw`` goes to
+    `core.layer_program.window_step` (``program`` and its lowering
+    options).
+    """
+    gidx, sidx = sel[0], sel[1]
+    out, cc, counts, drops = window_step(
+        params, tuple(v[gidx] for v in states), class_counts[gidx],
+        ev_xyc, ev_gate, alive, pre_dt, **kw)
+    states = tuple(v.at[sidx].set(o, mode="drop")
+                   for v, o in zip(states, out))
+    class_counts = class_counts.at[sidx].set(cc, mode="drop")
+    return states, class_counts, counts, drops
 
 
 class EventServeEngine:
@@ -337,6 +360,9 @@ class EventServeEngine:
                       "evicted": 0,
                       "collector_dropped": 0, "out_of_range_dropped": 0,
                       "step_calls": 0, "kernel_launches": 0,
+                      # window steps whose slot batch is not the identity
+                      # (fewer slots than N): slot compaction engaged
+                      "compacted_windows": 0,
                       "dense_slot_windows": 0, "skipped_slot_windows": 0,
                       "leak_flushes": 0,
                       # padding-waste accounting: real events collected vs
@@ -368,12 +394,15 @@ class EventServeEngine:
         self.bucket_fill_hist = np.zeros(
             (int(self.caps[0]).bit_length() + 2,), np.int64)
 
-        # the jitted per-window step IS the unified program executor —
-        # every layer kind is one slot-batched scatter launch per timestep
-        step_fn = partial(window_step, program=self.program, co_blk=co_blk,
-                          use_pallas=use_pallas)
+        # the jitted per-window program: the slot gather, the unified
+        # program executor and the scatter-back in ONE dispatch, compacted
+        # or not (a full in-order batch takes the resident identity index)
+        step_fn = partial(batched_window_step, program=self.program,
+                          co_blk=co_blk, use_pallas=use_pallas)
         self._step = jax.jit(step_fn, donate_argnums=(1, 2)
                              if donate_buffers else ())
+        ident = np.arange(n_slots, dtype=np.int32)
+        self._identity_sel = self._put(np.stack([ident, ident]))
 
         # slot teardown fused into one dispatch: zeroing every membrane
         # slab row plus the class-count row and reading the finished
@@ -690,12 +719,18 @@ class EventServeEngine:
                        max_bucket: int, seq: int) -> InflightWindow:
         """Compact the active slots and dispatch the batched window step.
 
-        Without ``idle_skip`` this degenerates to the original full-batch
-        step (all N slots, full event axis) — the dense reference path the
-        skip path is tested bit-for-bit against.
+        Batch row ``i < len(idx)`` carries slot ``idx[i]``; the tail rows
+        are gated off, frozen and dropped at scatter-back.  Without
+        ``idle_skip`` every slot rides along (the non-participating ones
+        fill the tail) on the full event axis — the dense reference path
+        the skip path is tested bit-for-bit against.
 
-        The dispatch is asynchronous: the returned record carries the
-        per-window count/drop futures, and the membrane slabs /
+        The only device work here is the input puts and ONE call of the
+        jitted ``_step``, which gathers the batch from the resident slabs
+        and scatters it back itself; a full in-order batch passes the
+        resident identity index, so it is the same program with no index
+        put.  The dispatch is asynchronous: the returned record carries
+        the per-window count/drop futures, and the membrane slabs /
         class-count accumulators are replaced by their post-window
         futures immediately (with ``donate_buffers`` the old buffers are
         donated to the step, so slab state never round-trips).  Nothing
@@ -704,66 +739,49 @@ class EventServeEngine:
         A = len(idx)
         if self.idle_skip:
             # slot-axis compaction: power-of-two bucket, dummies mirror
-            # slot 0 but are gated off and frozen (alive == 0)
+            # slot 0
             Ab = self._bucket(A, self.N)
-            gidx = np.concatenate([idx, np.zeros((Ab - A,), idx.dtype)])
+            tail = np.zeros((Ab - A,), np.int64)
             # event-axis compaction: trim to this window's occupancy on
             # the adaptive ladder (pow2 kept as the waste counterfactual)
             Eb = event_bucket(max_bucket, self.caps[0])
             Eb_pow2 = self._bucket(max(max_bucket, 8), self.caps[0])
         else:
-            Ab, gidx = self.N, np.arange(self.N)
+            # dense: the slots not in this window (zero gate and alive
+            # from the collector) fill the tail
+            Ab = self.N
+            tail = np.setdiff1d(np.arange(self.N), idx)
             Eb = Eb_pow2 = self.caps[0]
+        gidx = np.concatenate([idx, tail])
         # deferred decay for slots (re)entering the dense path, fused into
-        # the window step (dummy tail positions mirror real slots' dt but
-        # their decayed state is discarded at scatter-back)
-        pre = np.zeros((len(gidx),), np.int64)
+        # the window step
+        pre = np.zeros((Ab,), np.int64)
         if self.idle_skip and self.pending_dt[idx].any():
             pre[:A] = self.pending_dt[idx]
             self.pending_dt[idx] = 0
             self.stats["leak_flushes"] += 1
+        # fancy indexing copies, so masking the tail leaves the
+        # collector's arrays alone
         xyc_w = xyc[:, gidx, :Eb]
         gate_w = gate[:, gidx, :Eb]
         alive_w = alive[:, gidx]
-        if self.idle_skip and Ab > A:
-            # only the *compacted* batch has dummy tail positions; in the
-            # dense branch gidx covers every slot (inactive ones already
-            # carry zero gate/alive from the collector) and masking the
-            # tail would wipe a real slot whenever the active set is not
-            # a prefix (e.g. slot 1 finished while 0 and 2 are mid-flight)
-            gate_w = gate_w.copy()
-            gate_w[:, A:] = 0.0
-            alive_w = alive_w.copy()
-            alive_w[:, A:] = 0.0
-        # the slot gather/scatter is only worth paying when the batch is
-        # actually compacted; a full in-order batch (idle_skip off, or
-        # every slot active) passes the state tuple straight through
-        full_batch = len(gidx) == self.N and (gidx == np.arange(self.N)).all()
-        if full_batch:
-            states_c, cc_c = self.states, self.class_counts
+        gate_w[:, A:] = 0.0
+        alive_w[:, A:] = 0.0
+        if np.array_equal(idx, np.arange(self.N)):
+            sel = self._identity_sel
         else:
-            gj = self._put(gidx)
-            states_c = tuple(v[gj] for v in self.states)
-            cc_c = self.class_counts[gj]
-        states_c, cc_c, counts, drops = self._step(
-            self.params, states_c, cc_c, self._put(xyc_w),
-            self._put(gate_w), self._put(alive_w), self._put(pre))
-        if full_batch:
-            # batch position == slot index
-            self.states = states_c
-            self.class_counts = cc_c
-        else:
-            # batch position i holds slot idx[i]
-            real = self._put(idx)
-            self.states = tuple(v.at[real].set(sc[:A])
-                                for v, sc in zip(self.states, states_c))
-            self.class_counts = self.class_counts.at[real].set(cc_c[:A])
+            sidx = np.concatenate([idx, np.full((Ab - A,), self.N)])
+            sel = self._put(np.stack([gidx, sidx]).astype(np.int32))
+            self.stats["compacted_windows"] += 1
+        self.states, self.class_counts, counts, drops = self._step(
+            self.params, self.states, self.class_counts, sel,
+            self._put(xyc_w), self._put(gate_w), self._put(alive_w),
+            self._put(pre))
         self.dense_ts[idx] += alive[:, idx].sum(axis=0).astype(np.int64)
         self.stats["step_calls"] += 1
-        self.stats["launched_events"] += int(
-            np.sum(gate_w[:, :A] if not full_batch else gate_w[:, idx]))
-        self.stats["padded_event_slots"] += self.W * len(gidx) * Eb
-        self.stats["padded_event_slots_pow2"] += self.W * len(gidx) * Eb_pow2
+        self.stats["launched_events"] += int(np.sum(gate_w))
+        self.stats["padded_event_slots"] += self.W * Ab * Eb
+        self.stats["padded_event_slots_pow2"] += self.W * Ab * Eb_pow2
         self.stats["launch_bytes"] += (xyc_w.nbytes + gate_w.nbytes
                                        + alive_w.nbytes)
         # fused-network: ONE launch for the whole window (or per-layer
@@ -778,8 +796,7 @@ class EventServeEngine:
             self.stats["kernel_launches"] += len(self.program.ops)
         else:
             self.stats["kernel_launches"] += self.W * len(self.program.ops)
-        return InflightWindow(idx=idx, n_compact=A, full_batch=full_batch,
-                              counts=counts, drops=drops, seq=seq)
+        return InflightWindow(idx=idx, counts=counts, drops=drops, seq=seq)
 
     def _retire_phase(self, w: InflightWindow) -> None:
         """Block on one in-flight window and apply its numpy accounting.
@@ -792,15 +809,10 @@ class EventServeEngine:
         with Span(self.phase_s, "serve.retire.wait", win=w.seq):
             counts_np = np.asarray(w.counts, np.float64)
             drops_np = np.asarray(w.drops, np.float64)
-        idx, A = w.idx, w.n_compact
-        if w.full_batch:
-            self.acc_counts[:, idx] += counts_np[:, idx]
-            self.acc_drops[:, idx] += drops_np[:, idx]
-            self.total_drops += drops_np[:, idx].sum(axis=1)
-        else:
-            self.acc_counts[:, idx] += counts_np[:, :A]
-            self.acc_drops[:, idx] += drops_np[:, :A]
-            self.total_drops += drops_np[:, :A].sum(axis=1)
+        A = len(w.idx)
+        self.acc_counts[:, w.idx] += counts_np[:, :A]
+        self.acc_drops[:, w.idx] += drops_np[:, :A]
+        self.total_drops += drops_np[:, :A].sum(axis=1)
 
     def inter_layer_drops(self) -> dict:
         """Engine-lifetime ring/capacity drop totals per layer boundary.

@@ -6,6 +6,12 @@ reference equivalence (bit-for-bit), and admission/release/drain of
 EventServeEngine — plus the pack_events range checks and mapping mode 1
 of the analytic model.
 """
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,12 +25,14 @@ except ImportError:           # container has no hypothesis; see the shim
 from repro.core import events as ev
 from repro.core.engine import SneConfig, inference_time_s
 from repro.core.policies import ExecutionPolicy
+from repro.core.quant import quantize_net
 from repro.core.sne_net import dense_apply, init_snn, spike_counts, tiny_net
 from repro.data.events_ds import TINY, batch_at
 from repro.kernels.event_conv.ops import event_conv_batched
 from repro.kernels.event_conv.ref import selfcheck_batched_bitexact
 from repro.serve.event_engine import (EventRequest, EventServeEngine,
-                                      default_step_capacities)
+                                      default_step_capacities, event_bucket)
+from repro.serve.runtime import ManualClock, StreamingRuntime
 from repro.serve.telemetry import (proportionality_r2, request_telemetry,
                                    summarize)
 
@@ -565,6 +573,247 @@ def test_non_prefix_active_set_after_release(idle_skip):
     for got, want in zip(reqs, solo):
         np.testing.assert_array_equal(got.class_counts, want.class_counts)
         assert got.telemetry.total_events == want.telemetry.total_events
+
+
+# ---------------------------------------------------------------------------
+# slot compaction inside the one jitted window program
+# ---------------------------------------------------------------------------
+
+HERE = Path(__file__).resolve().parent
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@pytest.fixture(scope="module")
+def int_net():
+    """tiny_net on the int4 grid: integer arithmetic, so the engine and
+    `dense_apply` agree exactly."""
+    spec = tiny_net()
+    return quantize_net(init_snn(jax.random.PRNGKey(0), spec), spec)
+
+
+def _busy_spikes(T, in_shape, seed, k=6):
+    """``(T, H, W, C)`` spikes with ``k`` events at every timestep, so no
+    window of the request is idle-skipped."""
+    H, W, C = in_shape
+    rng = np.random.default_rng(seed)
+    s = np.zeros((T, H * W * C), np.float32)
+    for t in range(T):
+        s[t, rng.choice(H * W * C, size=k, replace=False)] = 1.0
+    return s.reshape(T, H, W, C)
+
+
+def _serve_placed(net, n_slots, placed, idle_skip, **kw):
+    """Serve each ``(slot, spikes)`` pinned to its slot; (requests,
+    engine)."""
+    eng = EventServeEngine(net.spec, net.params_for("f32-carrier"),
+                           n_slots=n_slots, window=4, use_pallas=False,
+                           policy=ExecutionPolicy(idle_skip=idle_skip), **kw)
+    reqs = []
+    for uid, (slot, s) in enumerate(placed):
+        r = EventRequest.from_dense(uid, jnp.asarray(s))
+        assert eng.try_admit(r, slot=slot)
+        reqs.append(r)
+    while eng.step():
+        pass
+    return reqs, eng
+
+
+def _assert_dense_parity(net, got, dense, spikes):
+    """Bitwise equal to the dense engine and to `dense_apply`."""
+    params = net.params_for("f32-carrier")
+    for g, d, s in zip(got, dense, spikes):
+        np.testing.assert_array_equal(g.class_counts, d.class_counts)
+        assert g.telemetry.per_layer_events == d.telemetry.per_layer_events
+        assert (g.telemetry.inter_layer_dropped
+                == d.telemetry.inter_layer_dropped)
+        out, _ = dense_apply(params, net.spec, jnp.asarray(s))
+        np.testing.assert_array_equal(g.class_counts,
+                                      np.asarray(spike_counts(out)))
+
+
+@pytest.mark.parametrize("n_slots,slots,compacted", [
+    (8, (0, 2, 5), True),        # A = 3, Ab = 4: the dummy reads slot 0
+    (10, (1, 4, 7, 9), True),    # A = Ab = 4: no dummies
+    (4, (0, 1, 2, 3), False),    # full in-order batch: the identity index
+], ids=["slot0-mirrored", "no-dummies", "full-batch"])
+def test_compacted_window_parity(int_net, n_slots, slots, compacted):
+    """One jitted program gathers, steps and scatters back the batch.
+
+    With busy slots {0, 2, 5} the dummy tail reads slot 0, which is also
+    a real row: the dummy must be dropped at scatter-back, not written
+    over slot 0.  Each case matches the dense engine (idle skip off)
+    and `dense_apply` bitwise."""
+    T = int_net.spec.n_timesteps
+    spikes = [_busy_spikes(T, int_net.spec.in_shape, seed=s) for s in slots]
+    placed = list(zip(slots, spikes))
+    got, eng = _serve_placed(int_net, n_slots, placed, idle_skip=True,
+                             donate_buffers=True)
+    dense, _ = _serve_placed(int_net, n_slots, placed, idle_skip=False)
+    n_win = T // eng.W
+    assert eng.stats["step_calls"] == n_win
+    assert eng.stats["compacted_windows"] == (n_win if compacted else 0)
+    _assert_dense_parity(int_net, got, dense, spikes)
+
+
+def test_compacted_non_prefix_after_release_streaming(int_net):
+    """Under the streaming runtime a short request's release leaves a
+    non-prefix busy set ({0, 2, 3} while slot 1 waits to retire) and a
+    queued request then takes the freed slot; every answer matches the
+    dense engine and `dense_apply` bitwise."""
+    lengths = [16, 4, 16, 8, 12]
+    spikes = [_busy_spikes(t, int_net.spec.in_shape, seed=20 + i)
+              for i, t in enumerate(lengths)]
+    eng = EventServeEngine(int_net.spec, int_net.params_for("f32-carrier"),
+                           n_slots=4, window=4, use_pallas=False,
+                           donate_buffers=True)
+    rt = StreamingRuntime(eng, queue_capacity=8, clock=ManualClock())
+    got = [EventRequest.from_dense(i, jnp.asarray(s))
+           for i, s in enumerate(spikes)]
+    rt.submit(got)
+    assert rt.serve()["completed"] == len(got)
+    assert eng.stats["compacted_windows"] > 0
+    dense_eng = EventServeEngine(
+        int_net.spec, int_net.params_for("f32-carrier"), n_slots=4,
+        window=4, use_pallas=False, policy=ExecutionPolicy(idle_skip=False))
+    dense = [EventRequest.from_dense(i, jnp.asarray(s))
+             for i, s in enumerate(spikes)]
+    dense_eng.run(dense)
+    _assert_dense_parity(int_net, got, dense, spikes)
+
+
+def test_compacted_windows_compile_nothing_after_warm_up(int_net):
+    """After one warm-up window at every busy count A in 1..N, windows
+    over non-prefix busy sets compile nothing, and the step holds one
+    program per (Ab, Eb) pair: the identity batch (A = N) and the
+    compacted batches with Ab = N share a program."""
+    N, T, k = 4, 4, 6
+    eng = EventServeEngine(int_net.spec, int_net.params_for("f32-carrier"),
+                           n_slots=N, window=T, use_pallas=False,
+                           donate_buffers=True)
+    shape = int_net.spec.in_shape
+
+    def cohort(slots, seed):
+        return [(s, EventRequest.from_dense(
+            s, jnp.asarray(_busy_spikes(T, shape, seed + s, k))))
+            for s in slots]
+
+    def serve(placed):
+        for slot, r in placed:
+            assert eng.try_admit(r, slot=slot)
+        while eng.step():
+            pass
+
+    for a in range(1, N + 1):
+        serve(cohort(range(a), seed=100 * a))
+    programs = eng._step._cache_size()
+    pairs = {(eng._bucket(a, N), event_bucket(k, eng.caps[0]))
+             for a in range(1, N + 1)}
+    assert programs == len(pairs) == 3
+
+    sets = [(1, 3), (0, 2, 3), (2,), (0, 1, 2, 3), (1, 2, 3), (0, 3)]
+    cohorts = [cohort(s, seed=1000 + 10 * i) for i, s in enumerate(sets)]
+    c0 = dict(eng.stats)
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == COMPILE_EVENT:
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for placed in cohorts:
+            serve(placed)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiles == []
+    assert eng._step._cache_size() == programs
+    assert eng.stats["step_calls"] - c0["step_calls"] == len(sets)
+    assert (eng.stats["compacted_windows"] - c0["compacted_windows"]
+            == sum(len(s) < N for s in sets))
+    assert all(r.done for placed in cohorts for _, r in placed)
+
+
+def test_launch_window_dispatches_no_eager_primitive(int_net,
+                                                     monkeypatch):
+    """A compacted window's launch starts no eager device op: the slot
+    gather and scatter-back run inside the one jitted ``_step``, so
+    ``_launch_window`` only puts its inputs and makes that call."""
+    from jax._src import dispatch
+
+    T = 8
+    shape = int_net.spec.in_shape
+    eng = EventServeEngine(int_net.spec, int_net.params_for("f32-carrier"),
+                           n_slots=4, window=4, use_pallas=False,
+                           donate_buffers=True)
+    for s in (0, 2, 3):
+        r = EventRequest.from_dense(
+            s, jnp.asarray(_busy_spikes(T, shape, seed=s)))
+        assert eng.try_admit(r, slot=s)
+    eager = []
+    apply = dispatch.apply_primitive
+
+    def counting(prim, *a, **k):
+        eager.append(prim)
+        return apply(prim, *a, **k)
+
+    launch = eng._launch_window
+
+    def watched(*a, **k):
+        with monkeypatch.context() as m:
+            m.setattr(dispatch, "apply_primitive", counting)
+            return launch(*a, **k)
+
+    eng._launch_window = watched
+    while eng.step():
+        pass
+    assert eng.stats["compacted_windows"] == eng.stats["step_calls"] == 2
+    assert eager == []
+
+
+def test_compacted_windows_counts_non_identity_local(int_net):
+    """``compacted_windows`` counts the window steps whose batch is not
+    the identity: a full cohort adds none, a prefix or a non-prefix
+    subset one per window, and a dense engine (idle skip off) with an
+    idle slot counts too, since its batch leaves that slot out."""
+    T = int_net.spec.n_timesteps
+    shape = int_net.spec.in_shape
+    n_win = T // 4
+    for slots, idle_skip, want in [((0, 1, 2, 3), True, 0),
+                                   ((0, 1, 2), True, n_win),
+                                   ((1, 3), True, n_win),
+                                   ((0, 1, 2, 3), False, 0),
+                                   ((0, 2), False, n_win)]:
+        placed = [(s, _busy_spikes(T, shape, seed=s)) for s in slots]
+        _, eng = _serve_placed(int_net, 4, placed, idle_skip=idle_skip)
+        assert eng.stats["step_calls"] == n_win, slots
+        assert eng.stats["compacted_windows"] == want, (slots, idle_skip)
+
+
+def test_compacted_windows_counts_non_identity_mesh():
+    """On the mesh engine over four CPU devices the counter sums the
+    shards': a per-shard window with one of a shard's two slots busy
+    counts, a shard with both busy does not, and a fused mesh step
+    (every shard busy) counts none (``tests/compact_mesh_check.py``)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [str(HERE.parent / "src"),
+                    os.environ.get("PYTHONPATH", "")]))
+    proc = subprocess.run(
+        [sys.executable, str(HERE / "compact_mesh_check.py")], env=env,
+        capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["devices"] == 4, out
+    # slots 0, 1 (shard 0, both) and 2 (shard 1, one of two); shards 2
+    # and 3 idle -> per-shard windows, shard 1's compacted
+    assert out["per_shard"] == {"compacted": 2, "shard_sum": 2,
+                                "step_calls": 4, "mesh_shard_windows": 2,
+                                "mesh_global_windows": 0}, out
+    # one slot of every shard busy -> fused mesh steps, nothing compacted
+    assert out["fused"] == {"compacted": 0, "shard_sum": 0,
+                            "step_calls": 2, "mesh_shard_windows": 0,
+                            "mesh_global_windows": 2}, out
 
 
 def test_boundary_cost_credits_idle_skip():

@@ -35,6 +35,7 @@ from repro.kernels.event_fc import ops as fc_ops
 from repro.kernels.event_pool import kernel as pool_k
 from repro.kernels.event_pool import ops as pool_ops
 from repro.kernels.window_common import tile_grid
+from repro.serve.event_engine import batched_window_step
 
 N_SLOTS = 4
 WINDOW = 4
@@ -151,3 +152,32 @@ def test_window_step_compiles_for_v5e(one_chip, monkeypatch):
                             rf'custom_call_target="tpu_custom_call"')
         assert sum(bool(launch.match(line))
                    for line in text.splitlines()) == 1, name
+
+
+def test_engine_window_program_compiles_for_v5e(one_chip, monkeypatch):
+    """The engine's one window program — slot gather, fused-window step,
+    scatter-back with the dummy tail dropped — for a compacted batch of 4
+    of 16 slots at the smallest rung, the slabs donated: they must alias
+    the outputs, so the scatter writes the resident state in place."""
+    for mod in (conv_ops, pool_ops, fc_ops):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    sh = one_chip
+    spec = dvs_gesture_net()
+    program = lp.compile_program(spec, policy=ExecutionPolicy())
+    params = jax.eval_shape(lambda: init_snn(jax.random.PRNGKey(0), spec))
+    params = jax.tree.map(lambda a: _shape(sh, a.shape, a.dtype), params)
+    N, Ab = 16, 4
+    states = tuple(_shape(sh, (N,) + lp.padded_state(op).shape)
+                   for op in program.ops)
+    cc = _shape(sh, (N, spec.n_classes))
+    sel = _shape(sh, (2, Ab), jnp.int32)
+    xyc, gate = _events(sh, (WINDOW, Ab), 8)
+    alive = _shape(sh, (WINDOW, Ab))
+    pre = _shape(sh, (Ab,), jnp.int32)
+    step = jax.jit(functools.partial(batched_window_step, program=program),
+                   donate_argnums=(1, 2))
+    compiled = step.lower(params, states, cc, sel, xyc, gate, alive,
+                          pre).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= len(program.ops)
+    slab_bytes = sum(4 * a.size for a in states + (cc,))
+    assert compiled.memory_analysis().alias_size_in_bytes >= slab_bytes
